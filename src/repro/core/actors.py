@@ -167,7 +167,8 @@ class DeviceSpec:
     def build_mesh(self):
         if not self.mesh_shape:
             return None
-        return jax.make_mesh(tuple(self.mesh_shape), tuple(self.mesh_axes))
+        from repro.launch.mesh import make_mesh
+        return make_mesh(self.mesh_shape, self.mesh_axes)
 
 
 # --------------------------------------------------------------- transports --
@@ -1449,6 +1450,20 @@ def _next_socket_address() -> Optional[Tuple[str, int]]:
     return (host or "127.0.0.1", int(port))
 
 
+def _refuse_local_child_on_tpu(transport: str):
+    """A TPU chip belongs to one process: once this process has brought
+    up a TPU backend, a local child that needs the chip would fail or sit
+    out the whole spawn handshake.  Say so at once instead."""
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized() and \
+            jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"transport {transport!r} would start a local child actor, but "
+            "this process already holds the TPU (a chip belongs to one "
+            "process).  Use transport='inproc' with submeshes on one host, "
+            "or start the controller before anything touches JAX.")
+
+
 @dataclass(frozen=True)
 class SpawnSpec:
     """Everything needed to (re)build an actor identically: recorded on
@@ -1474,6 +1489,8 @@ class SpawnSpec:
                     self.device_spec.mesh_shape and "mesh" not in kwargs:
                 kwargs["mesh"] = self.device_spec.build_mesh()
             return InprocTransport(self.factory(*self.args, **kwargs))
+        if self.address is None or self.transport != "socket":
+            _refuse_local_child_on_tpu(self.transport)
         if self.transport == "proc":
             return ProcTransport(
                 self.factory, self.args, kwargs,

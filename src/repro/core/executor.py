@@ -8,6 +8,7 @@ and generator work on disjoint devices.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -16,6 +17,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import ddma
 from repro.core.aipo import token_logprobs
@@ -62,6 +64,21 @@ class Executor:
 
     def init(self):
         pass
+
+    def _on_mesh(self):
+        """Context for tracing and running this executor's programs: its
+        submesh, so eager work lands there and the kernel dispatch runs
+        Pallas kernels per device (none without a submesh)."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
+
+    def _place(self, tree):
+        """Replicate ``tree`` over this executor's submesh (identity
+        without one), so its work runs on its own devices."""
+        if self.mesh is None:
+            return tree
+        return jax.device_put(tree, NamedSharding(self.mesh, P()))
 
     def set_step(self, i: int):
         self.curr_step = i
@@ -180,7 +197,7 @@ class GeneratorExecutor(Executor):
         self.temperature = temperature
         self.quantize = quantize
         self.chunk = chunk
-        self.key = jax.random.PRNGKey(seed)
+        self.key = self._place(jax.random.PRNGKey(seed))
         self.params = None
         self.weight_version = -1        # version of self.params (-1 = unset)
         self._pinned: Dict[int, Any] = {}    # admission snapshots by pin key
@@ -218,12 +235,13 @@ class GeneratorExecutor(Executor):
         if self.max_new <= 0:
             raise ValueError(f"max_new must be >= 1, got {self.max_new}")
         batch = self.tasks.sample(self.n_prompts, self.n_per_prompt)
-        prompts = jnp.asarray(batch.prompts)
+        prompts = self._place(jnp.asarray(batch.prompts))
         self.key, sub = jax.random.split(self.key)
         chunk = self.chunk or self.max_new
         n_chunks = -(-self.max_new // chunk)
-        state = start_rollout(self.params, self.cfg, prompts,
-                              prompts.shape[1] + n_chunks * chunk)
+        with self._on_mesh():
+            state = start_rollout(self.params, self.cfg, prompts,
+                                  prompts.shape[1] + n_chunks * chunk)
         job = RolloutJob(
             batch_index=self.curr_step if batch_index is None
             else batch_index,
@@ -288,10 +306,11 @@ class GeneratorExecutor(Executor):
 
     def advance_chunk(self, job, state):
         """One resumable ``rollout_chunk`` with the job's key discipline."""
-        job.key, sub = jax.random.split(job.key)
-        state = rollout_chunk(self._job_params(job), self.cfg, state, sub,
-                              n_steps=job.chunk,
-                              temperature=self.temperature)
+        with self._on_mesh():
+            job.key, sub = jax.random.split(job.key)
+            state = rollout_chunk(self._job_params(job), self.cfg, state,
+                                  sub, n_steps=job.chunk,
+                                  temperature=self.temperature)
         job.chunks_done += 1
         return state
 
@@ -368,7 +387,8 @@ class GeneratorExecutor(Executor):
         item is the caller-shaped sample-queue entry (batch snapshot
         included -- one round-trip per emitted batch, like
         ``emit_batch_snapshot``)."""
-        emissions = self._engine.round()
+        with self._on_mesh():
+            emissions = self._engine.round()
         items = []
         for e in emissions:
             self.set_output("completions", e["out"])
@@ -486,7 +506,8 @@ class RefPolicyExecutor(Executor):
                 return jnp.pad(lp, ((0, 0), (1, 0)))
             self._jitted = jax.jit(ref_logp)
         out = dict(comp)
-        out["ref_logp"] = self._jitted(self.params, comp["tokens"])
+        with self._on_mesh():
+            out["ref_logp"] = self._jitted(self.params, comp["tokens"])
         self.set_output("completions_with_ref", out)
         self.curr_step += 1
         return out
@@ -512,8 +533,13 @@ class TrainerExecutor(Executor):
         self.metrics_history = []
 
     def init(self):
-        self.state = init_train_state(self.cfg, jax.random.PRNGKey(self.seed),
-                                      self.dtype)
+        # with a submesh, initialize on its first device, then replicate:
+        # the same values as an unplaced init, never on another device
+        first = None if self.mesh is None else self.mesh.devices.flat[0]
+        with jax.default_device(first):
+            state = init_train_state(self.cfg, jax.random.PRNGKey(self.seed),
+                                     self.dtype)
+        self.state = self._place(state)
         self.set_output("policy_model", self.state.params)
 
     def get_model(self):
@@ -542,7 +568,8 @@ class TrainerExecutor(Executor):
         }
         if "ref_logp" in scored:
             batch["ref_logp"] = scored["ref_logp"]
-        self.state, metrics = self._jitted(self.state, batch)
+        with self._on_mesh():
+            self.state, metrics = self._jitted(self.state, batch)
         metrics = {k: float(v) for k, v in metrics.items()}
         metrics["mean_reward"] = scored.get("mean_reward", 0.0)
         self.metrics_history.append(metrics)

@@ -4,8 +4,9 @@ Replaces the ad-hoc ``INTERPRET`` flag that used to live in ``ops.py``.
 Every caller (trainer loss, reference scoring, decode sampling, dense-causal
 attention) goes through the public entry points here --
 ``token_logprob`` / ``sample`` / ``attention`` / ``int8_matmul`` -- and the
-routing policy picks one of three backends per call site from env, dtype and
-static shapes:
+routing policy picks one of three backends per call site from platform,
+env, dtype and static shapes (``auto``: compiled kernels above the size
+thresholds on a TPU, streamed jnp everywhere else):
 
 * ``pallas_compile``   -- Mosaic-lowered Pallas kernels (TPU).
 * ``pallas_interpret`` -- the Pallas interpreter (bit-accurate kernel
@@ -21,17 +22,20 @@ trainer's peak-memory hot spot at V = 256k (paper Sec. 6).
 Env knobs (read at trace time):
   REPRO_KERNEL_MODE       auto | compile | interpret | ref
   REPRO_PALLAS_COMPILE=1  legacy alias for REPRO_KERNEL_MODE=compile
-  REPRO_KERNEL_MIN_VOCAB  min vocab before compile mode uses Pallas (4096)
-  REPRO_KERNEL_MIN_SEQ    min seq len before compile mode uses Pallas (512)
+  REPRO_KERNEL_MIN_VOCAB  min vocab before a compiled kernel is used (4096)
+  REPRO_KERNEL_MIN_SEQ    min seq len before a compiled kernel is used (512)
   REPRO_LOGPROB_BLOCK_T/V, REPRO_SAMPLE_BLOCK_B/V, REPRO_ATTN_BLOCK
 """
 from __future__ import annotations
 
+import collections
 import functools
 import os
+import threading
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.fused_logprob import fused_logprob, fused_logprob_bwd
@@ -41,6 +45,11 @@ from repro.kernels.int8_matmul import int8_matmul as _int8mm
 from repro.kernels.online import NEG_INF, online_softmax_step
 
 _PALLAS_DTYPES = (jnp.float32, jnp.bfloat16, jnp.float16)
+
+# (hot path, backend) -> traces; a process-wide record of what was staged,
+# read by routes_taken (generator and trainer threads trace concurrently)
+_ROUTES: collections.Counter = collections.Counter()
+_ROUTES_LOCK = threading.Lock()
 
 
 def _env_int(name: str, default: int) -> int:
@@ -62,19 +71,52 @@ def kernel_mode() -> str:
     return "auto"
 
 
-def _route(n: int, dtype, threshold_var: str, default_min: int) -> str:
-    """Pick a backend for a call whose dominant streamed axis has size n."""
+def _route(path: str, n: int, dtype, threshold_var: str,
+           default_min: int) -> str:
+    """Pick a backend for hot path ``path`` whose dominant streamed axis
+    has size n, and count the choice (at trace time: once per compiled
+    call site, not per call) for ``routes_taken``."""
+    backend = _pick(n, dtype, threshold_var, default_min)
+    with _ROUTES_LOCK:
+        _ROUTES[(path, backend)] += 1
+    return backend
+
+
+def _pick(n: int, dtype, threshold_var: str, default_min: int) -> str:
     mode = kernel_mode()
     if mode == "ref" or dtype not in _PALLAS_DTYPES:
         return "jnp"
     if mode == "interpret":
         return "pallas_interpret"
-    if mode == "compile" and n >= _env_int(threshold_var, default_min):
+    # auto compiles the kernels where Mosaic exists (TPU); elsewhere the
+    # streamed-jnp path both lowers and beats the Pallas interpreter.
+    # Below the threshold kernel launch overhead dominates, so jnp.
+    wants = mode == "compile" or jax.default_backend() == "tpu"
+    if wants and n >= _env_int(threshold_var, default_min):
         return "pallas_compile"
-    # auto without REPRO_PALLAS_COMPILE: the streamed-jnp path both lowers
-    # everywhere and beats the Pallas interpreter on CPU; compile mode below
-    # the threshold also lands here (kernel launch overhead dominates).
     return "jnp"
+
+
+def _per_device(kernel):
+    """Mosaic kernels cannot be partitioned automatically.  Traced under
+    a multi-device mesh (an executor on its submesh, ``jax.set_mesh``),
+    the kernel runs on every device over whole, replicated operands;
+    elsewhere it is called as is."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return kernel
+    return jax.shard_map(kernel, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
+
+
+def routes_taken() -> dict:
+    """``{path: {backend: traces}}``: which backend each hot path was
+    traced with so far in this process."""
+    out: dict = {}
+    with _ROUTES_LOCK:
+        for (path, backend), n in _ROUTES.items():
+            out.setdefault(path, {})[backend] = n
+    return out
 
 
 # ------------------------------------------------------- token logprob ---
@@ -141,9 +183,10 @@ def _logprob_bwd_stream_jnp(logits, tokens, m, log_s, g, bv: int):
 def _logprob_fwd_impl(logits, tokens, backend: str, bt: int, bv: int):
     if backend == "jnp":
         return _logprob_stream_jnp(logits, tokens, bv)
-    out, m, s = fused_logprob(logits, tokens, block_t=bt, block_v=bv,
-                              interpret=backend != "pallas_compile",
-                              return_stats=True)
+    out, m, s = _per_device(functools.partial(
+        fused_logprob, block_t=bt, block_v=bv,
+        interpret=backend != "pallas_compile", return_stats=True))(
+            logits, tokens)
     return out, m, jnp.log(s)
 
 
@@ -162,9 +205,10 @@ def _token_logprob_2d_bwd(backend, bt, bv, res, g):
     if backend == "jnp":
         dl = _logprob_bwd_stream_jnp(logits, tokens, m, log_s, g, bv)
     else:
-        dl = fused_logprob_bwd(logits, tokens, m, log_s, g, block_t=bt,
-                               block_v=bv,
-                               interpret=backend != "pallas_compile")
+        dl = _per_device(functools.partial(
+            fused_logprob_bwd, block_t=bt, block_v=bv,
+            interpret=backend != "pallas_compile"))(
+                logits, tokens, m, log_s, g)
     return dl, None
 
 
@@ -183,7 +227,8 @@ def token_logprob(logits, tokens, *, block_t: int = 0, block_v: int = 0):
     lead = logits.shape[:-1]
     bt = block_t or _env_int("REPRO_LOGPROB_BLOCK_T", 256)
     bv = min(block_v or _env_int("REPRO_LOGPROB_BLOCK_V", 2048), V)
-    backend = _route(V, logits.dtype, "REPRO_KERNEL_MIN_VOCAB", 4096)
+    backend = _route("logprob", V, logits.dtype, "REPRO_KERNEL_MIN_VOCAB",
+                     4096)
     T = 1
     for d in lead:
         T *= d
@@ -241,11 +286,13 @@ def sample(logits, key, temperature: float, *, block_v: int = 0):
     B, V = logits.shape
     bv = min(block_v or _env_int("REPRO_SAMPLE_BLOCK_V", 2048), V)
     bb = _env_int("REPRO_SAMPLE_BLOCK_B", 256)
-    backend = _route(V, logits.dtype, "REPRO_KERNEL_MIN_VOCAB", 4096)
+    backend = _route("sample", V, logits.dtype, "REPRO_KERNEL_MIN_VOCAB",
+                     4096)
     if backend == "jnp":
         return _sample_stream_jnp(logits, key, temperature, bv)
-    return fused_sample(logits, key, temperature=temperature, block_b=bb,
-                        block_v=bv, interpret=backend != "pallas_compile")
+    return _per_device(functools.partial(
+        fused_sample, temperature=temperature, block_b=bb, block_v=bv,
+        interpret=backend != "pallas_compile"))(logits, key)
 
 
 # ------------------------------------------------------------ attention ---
@@ -264,7 +311,8 @@ def _flash_padded(q, k, v, block: int, compiled: bool):
         # causal mask already excludes them; padded query rows are sliced off
         wid = ((0, 0), (0, pad), (0, 0), (0, 0))
         q, k, v = jnp.pad(q, wid), jnp.pad(k, wid), jnp.pad(v, wid)
-    out = _flash(q, k, v, block_q=b, block_k=b, interpret=not compiled)
+    out = _per_device(functools.partial(
+        _flash, block_q=b, block_k=b, interpret=not compiled))(q, k, v)
     return out[:, :S]
 
 
@@ -300,8 +348,8 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
     eligible = (causal and not window and q_offset == 0
                 and kv_positions is None and Sq == Sk
                 and v.shape[-1] == q.shape[-1] and H % K == 0)
-    backend = _route(Sq, q.dtype, "REPRO_KERNEL_MIN_SEQ", 512) \
-        if eligible else "jnp"
+    backend = _route("attention", Sq, q.dtype, "REPRO_KERNEL_MIN_SEQ",
+                     512) if eligible else "jnp"
     if backend == "jnp":
         return chunked_attention(q, k, v, causal=causal, window=window,
                                  block_q=block_q, q_offset=q_offset,
@@ -325,13 +373,15 @@ def paged_attention(q, arena_k, arena_v, page_table, pos, *, window: int = 0):
     from repro.kernels.paged_attention import (paged_attention_kernel,
                                                paged_attention_ref)
     S = (page_table.shape[1] - 1) * arena_k.shape[1]
-    backend = _route(S, q.dtype, "REPRO_KERNEL_MIN_SEQ", 512)
+    backend = _route("paged_attention", S, q.dtype, "REPRO_KERNEL_MIN_SEQ",
+                     512)
     if backend == "jnp":
         return paged_attention_ref(q, arena_k, arena_v, page_table, pos,
                                    window=window)
-    return paged_attention_kernel(q, arena_k, arena_v, page_table, pos,
-                                  window=window,
-                                  interpret=backend != "pallas_compile")
+    return _per_device(functools.partial(
+        paged_attention_kernel, window=window,
+        interpret=backend != "pallas_compile"))(
+            q, arena_k, arena_v, page_table, pos)
 
 
 # --------------------------------------------------------------- matmul ---
@@ -342,9 +392,11 @@ def int8_matmul(x, w_q, scale, *, block_m: int = 256, block_n: int = 256,
     dequantize-then-dot otherwise.  (Dispatch surface for the int8 kernel;
     today's generator quantization dequantizes once at weight sync via
     ``ddma.quantize_dequant``, so only tests/benchmarks hit this yet.)"""
-    backend = _route(x.shape[-1], x.dtype, "REPRO_KERNEL_MIN_MATMUL", 1024)
+    backend = _route("int8_matmul", x.shape[-1], x.dtype,
+                     "REPRO_KERNEL_MIN_MATMUL", 1024)
     if backend == "jnp":
         from repro.kernels.ref import int8_matmul_ref
         return int8_matmul_ref(x, w_q, scale)
-    return _int8mm(x, w_q, scale, block_m=block_m, block_n=block_n,
-                   block_k=block_k, interpret=backend != "pallas_compile")
+    return _per_device(functools.partial(
+        _int8mm, block_m=block_m, block_n=block_n, block_k=block_k,
+        interpret=backend != "pallas_compile"))(x, w_q, scale)

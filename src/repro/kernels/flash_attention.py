@@ -53,7 +53,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 def flash_attention(q, k, v, *, block_q: int = 256, block_k: int = 256,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q: [B, S, H, hd]; k/v: [B, S, K, hd] -> [B, S, H, hd].  Causal."""
     B, S, H, hd = q.shape
     K = k.shape[2]

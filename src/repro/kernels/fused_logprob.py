@@ -47,29 +47,31 @@ def _kernel(tokens_ref, logits_ref, out_ref, m_out, s_out, m_ref, s_ref,
     m_ref[...] = m_new
     s_ref[...] = s_new
 
-    tok = tokens_ref[...]                                # [bt] global ids
-    local = tok - j * bv
-    in_blk = (local >= 0) & (local < bv)
-    idx = jnp.clip(local, 0, bv - 1)
-    vals = jnp.take_along_axis(block, idx[:, None], axis=1)[:, 0]
-    t_ref[...] = jnp.where(in_blk, vals, t_ref[...])
+    # one-hot select of the target logit (Mosaic has no lowering for a
+    # lane gather): the max over a single unmasked column is that value
+    hit = cols == tokens_ref[...]                        # [bt, 1] ids
+    vals = jnp.max(jnp.where(hit, block, -jnp.inf), axis=1)
+    t_ref[...] = jnp.where(jnp.any(hit, axis=1), vals, t_ref[...])
 
     @pl.when(j == n_vblocks - 1)
     def _fin():
         # subtract m before log s: with extreme logits (|m| ~ 1e30) the sum
         # m + log s absorbs log s entirely in fp32
-        out_ref[...] = (t_ref[...] - m_ref[...]) - jnp.log(s_ref[...])
-        m_out[...] = m_ref[...]
-        s_out[...] = s_ref[...]
+        out_ref[...] = ((t_ref[...] - m_ref[...])
+                        - jnp.log(s_ref[...]))[:, None]
+        m_out[...] = m_ref[...][:, None]
+        s_out[...] = s_ref[...][:, None]
 
 
 def fused_logprob(logits, tokens, *, block_t: int = 256,
-                  block_v: int = 2048, interpret: bool = True,
+                  block_v: int = 2048, interpret: bool = False,
                   return_stats: bool = False):
     """logits: [T, V]; tokens: [T] int32 -> logprobs [T] fp32.
 
     With ``return_stats=True`` returns ``(logprobs, m, s)`` where
-    ``logZ = m + log s`` (the VJP residuals).
+    ``logZ = m + log s`` (the VJP residuals).  Per-row operands cross
+    HBM as [T, 1] columns: a 1-D (bt,) block clashes with the tiled
+    layout XLA gives a 1-D array longer than one block.
     """
     T, V = logits.shape
     bt = min(block_t, T)
@@ -82,30 +84,25 @@ def fused_logprob(logits, tokens, *, block_t: int = 256,
         tokens = jnp.pad(tokens, (0, pad_t))
     Tp, Vp = logits.shape
     n_vblocks = Vp // bv
+    col = pl.BlockSpec((bt, 1), lambda i, j: (i, 0))
     out, m, s = pl.pallas_call(
         functools.partial(_kernel, bt=bt, bv=bv, n_vblocks=n_vblocks,
                           v_true=V),
         grid=(Tp // bt, n_vblocks),
-        in_specs=[
-            pl.BlockSpec((bt,), lambda i, j: (i,)),
-            pl.BlockSpec((bt, bv), lambda i, j: (i, j)),
-        ],
-        out_specs=[pl.BlockSpec((bt,), lambda i, j: (i,)),
-                   pl.BlockSpec((bt,), lambda i, j: (i,)),
-                   pl.BlockSpec((bt,), lambda i, j: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((Tp,), jnp.float32),
-                   jax.ShapeDtypeStruct((Tp,), jnp.float32),
-                   jax.ShapeDtypeStruct((Tp,), jnp.float32)],
+        in_specs=[col, pl.BlockSpec((bt, bv), lambda i, j: (i, j))],
+        out_specs=[col, col, col],
+        out_shape=[jax.ShapeDtypeStruct((Tp, 1), jnp.float32)] * 3,
         scratch_shapes=[
             pltpu.VMEM((bt,), jnp.float32),
             pltpu.VMEM((bt,), jnp.float32),
             pltpu.VMEM((bt,), jnp.float32),
         ],
         interpret=interpret,
-    )(tokens, logits)
+    )(tokens[:, None], logits)
+    out, m, s = out[:T, 0], m[:T, 0], s[:T, 0]
     if return_stats:
-        return out[:T], m[:T], s[:T]
-    return out[:T]
+        return out, m, s
+    return out
 
 
 def _bwd_kernel(tokens_ref, logits_ref, m_ref, ls_ref, g_ref, dl_ref, *,
@@ -116,15 +113,15 @@ def _bwd_kernel(tokens_ref, logits_ref, m_ref, ls_ref, g_ref, dl_ref, *,
     m does not absorb log s (same fp32 caveat as the forward)."""
     j = pl.program_id(1)
     block = logits_ref[...].astype(jnp.float32)
-    p = jnp.exp((block - m_ref[...][:, None]) - ls_ref[...][:, None])
+    p = jnp.exp((block - m_ref[...]) - ls_ref[...])      # [bt, 1] stats
     local = tokens_ref[...] - j * bv
     cols = jax.lax.broadcasted_iota(jnp.int32, (bt, bv), 1)
-    onehot = (cols == local[:, None]).astype(jnp.float32)
-    dl_ref[...] = ((onehot - p) * g_ref[...][:, None]).astype(dl_ref.dtype)
+    onehot = (cols == local).astype(jnp.float32)
+    dl_ref[...] = ((onehot - p) * g_ref[...]).astype(dl_ref.dtype)
 
 
 def fused_logprob_bwd(logits, tokens, m, log_s, g, *, block_t: int = 256,
-                      block_v: int = 2048, interpret: bool = True):
+                      block_v: int = 2048, interpret: bool = False):
     """Streaming VJP: logits [T, V], tokens/m/log_s/g [T] -> dlogits [T, V].
 
     Each grid cell is independent (no carry): the tile's softmax is
@@ -144,18 +141,14 @@ def fused_logprob_bwd(logits, tokens, m, log_s, g, *, block_t: int = 256,
         log_s = jnp.pad(log_s, (0, pad_t))
         g = jnp.pad(g, (0, pad_t))
     Tp, Vp = logits.shape
+    col = pl.BlockSpec((bt, 1), lambda i, j: (i, 0))
     out = pl.pallas_call(
         functools.partial(_bwd_kernel, bt=bt, bv=bv),
         grid=(Tp // bt, Vp // bv),
-        in_specs=[
-            pl.BlockSpec((bt,), lambda i, j: (i,)),
-            pl.BlockSpec((bt, bv), lambda i, j: (i, j)),
-            pl.BlockSpec((bt,), lambda i, j: (i,)),
-            pl.BlockSpec((bt,), lambda i, j: (i,)),
-            pl.BlockSpec((bt,), lambda i, j: (i,)),
-        ],
+        in_specs=[col, pl.BlockSpec((bt, bv), lambda i, j: (i, j)),
+                  col, col, col],
         out_specs=pl.BlockSpec((bt, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Tp, Vp), logits.dtype),
         interpret=interpret,
-    )(tokens, logits, m, log_s, g)
+    )(tokens[:, None], logits, m[:, None], log_s[:, None], g[:, None])
     return out[:T, :V]

@@ -53,7 +53,9 @@ def hash_uniform(rows, cols, k0, k1):
     dense reference."""
     x = _mix(rows.astype(jnp.uint32) * jnp.uint32(0x9E3779B9) + k0)
     x = _mix(x + cols.astype(jnp.uint32) * jnp.uint32(0x85EBCA6B) + k1)
-    mant = (x >> jnp.uint32(8)).astype(jnp.float32)      # 24 random bits
+    # 24 random bits fit int32 exactly; Mosaic casts int32 -> f32 but
+    # not uint32 -> f32
+    mant = (x >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
     return (mant + 0.5) * (1.0 / (1 << 24))
 
 
@@ -93,25 +95,31 @@ def _kernel(key_ref, logits_ref, tok_ref, lp_ref, m_ref, s_ref, best_ref,
         z = z + gumbel_noise(rows, cols, key_ref[0], key_ref[1])
     z = jnp.where(valid, z, -jnp.inf)
     tile_best = jnp.max(z, axis=-1)
-    tile_arg = jnp.argmax(z, axis=-1).astype(jnp.int32)
+    # first argmax and its logit by iota / one-hot select: Mosaic lowers
+    # neither argmax nor a lane gather
+    at_best = z == tile_best[:, None]
+    tile_tok = jnp.min(jnp.where(at_best, cols, jnp.iinfo(jnp.int32).max),
+                       axis=-1)
     # strict > keeps the earliest tile on ties -> global first-argmax
     better = tile_best > best_ref[...]
-    chosen = jnp.take_along_axis(block, tile_arg[:, None], axis=1)[:, 0]
-    btok_ref[...] = jnp.where(better, j * bv + tile_arg, btok_ref[...])
+    chosen = jnp.max(jnp.where(cols == tile_tok[:, None], block, -jnp.inf),
+                     axis=-1)
+    btok_ref[...] = jnp.where(better, tile_tok, btok_ref[...])
     blog_ref[...] = jnp.where(better, chosen, blog_ref[...])
     best_ref[...] = jnp.maximum(best_ref[...], tile_best)
 
     @pl.when(j == n_vblocks - 1)
     def _fin():
-        tok_ref[...] = btok_ref[...]
+        tok_ref[...] = btok_ref[...][:, None]
         # subtract m before log s (extreme-|m| fp32 absorption, see
         # fused_logprob)
-        lp_ref[...] = (blog_ref[...] - m_ref[...]) - jnp.log(s_ref[...])
+        lp_ref[...] = ((blog_ref[...] - m_ref[...])
+                       - jnp.log(s_ref[...]))[:, None]
 
 
 def fused_sample(logits, key, *, temperature: float = 1.0,
                  block_b: int = 256, block_v: int = 2048,
-                 interpret: bool = True):
+                 interpret: bool = False):
     """logits: [B, V]; key: PRNGKey -> (tokens [B] int32, logprob [B] fp32).
 
     ``logprob`` is the chosen token's log-prob under the sampling
@@ -136,13 +144,15 @@ def fused_sample(logits, key, *, temperature: float = 1.0,
             noisy=temperature > 0.0),
         grid=(Bp // bb, n_vblocks),
         in_specs=[
-            pl.BlockSpec((2,), lambda i, j: (0,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # scalar key words
             pl.BlockSpec((bb, bv), lambda i, j: (i, j)),
         ],
-        out_specs=[pl.BlockSpec((bb,), lambda i, j: (i,)),
-                   pl.BlockSpec((bb,), lambda i, j: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((Bp,), jnp.int32),
-                   jax.ShapeDtypeStruct((Bp,), jnp.float32)],
+        # [B, 1] columns: a 1-D (bb,) block clashes with XLA's tiled
+        # layout of a longer 1-D array (see fused_logprob)
+        out_specs=[pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
+                   pl.BlockSpec((bb, 1), lambda i, j: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((Bp, 1), jnp.float32)],
         scratch_shapes=[
             pltpu.VMEM((bb,), jnp.float32),
             pltpu.VMEM((bb,), jnp.float32),
@@ -152,4 +162,4 @@ def fused_sample(logits, key, *, temperature: float = 1.0,
         ],
         interpret=interpret,
     )(kd, logits)
-    return tok[:B], lp[:B]
+    return tok[:B, 0], lp[:B, 0]

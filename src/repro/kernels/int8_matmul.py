@@ -28,12 +28,11 @@ def _kernel(x_ref, wq_ref, scale_ref, o_ref, acc_ref, *, n_kblocks: int):
 
     @pl.when(kblk == n_kblocks - 1)
     def _fin():
-        o_ref[...] = (acc_ref[...] * scale_ref[...][None, :]).astype(
-            o_ref.dtype)
+        o_ref[...] = (acc_ref[...] * scale_ref[...]).astype(o_ref.dtype)
 
 
 def int8_matmul(x, w_q, scale, *, block_m: int = 256, block_n: int = 256,
-                block_k: int = 512, interpret: bool = True,
+                block_k: int = 512, interpret: bool = False,
                 out_dtype=jnp.float32):
     """x: [M, K] float; w_q: [K, N] int8; scale: [N] f32 -> [M, N]."""
     M, K = x.shape
@@ -54,11 +53,12 @@ def int8_matmul(x, w_q, scale, *, block_m: int = 256, block_n: int = 256,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
+            # 2-D: a 1-D (bn,) block conflicts with XLA's T(1024) layout
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
-    )(x, w_q, scale)
+    )(x, w_q, scale.reshape(1, Np))
     return out[:M, :N]

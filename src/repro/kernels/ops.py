@@ -1,12 +1,12 @@
 """Jit'd always-Pallas wrappers for the kernels (tests and benchmarks).
 
-These force the Pallas body to execute -- interpreted on CPU, Mosaic-lowered
-when ``REPRO_PALLAS_COMPILE=1`` -- so kernel-parity tests exercise the
-kernel semantics no matter what the routing policy would pick.  Production
-call sites (trainer loss, reference scoring, decode sampling, attention) go
-through ``repro.kernels.dispatch`` instead, which owns the full
-env/dtype/shape routing between compiled, interpreted and streamed-jnp
-backends.
+These force the Pallas body to execute -- Mosaic-lowered on a TPU or when
+``REPRO_KERNEL_MODE=compile``, interpreted elsewhere -- so kernel-parity
+tests exercise the kernel semantics no matter what the routing policy
+would pick.  Production call sites (trainer loss, reference scoring,
+decode sampling, attention) go through ``repro.kernels.dispatch``
+instead, which owns the full env/dtype/shape routing between compiled,
+interpreted and streamed-jnp backends.
 """
 from __future__ import annotations
 
@@ -22,7 +22,10 @@ from repro.kernels.int8_matmul import int8_matmul as _int8mm
 
 
 def _interpret() -> bool:
-    return kernel_mode() != "compile"
+    mode = kernel_mode()
+    if mode == "interpret":
+        return True
+    return mode != "compile" and jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_v"))

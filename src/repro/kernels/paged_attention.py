@@ -84,8 +84,8 @@ def _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(j * P <= pos_ref[b])       # block holds at least one valid col
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)               # [g, hd]
-        k = k_ref[0, :, 0].astype(jnp.float32)            # [P, hd]
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)                  # [P, hd]
+        v = v_ref[0].astype(jnp.float32)
         s = (q @ k.T) * scale                             # [g, P]
         g_dim = s.shape[0]
         cols = j * P + jax.lax.broadcasted_iota(jnp.int32, (g_dim, P), 1)
@@ -110,7 +110,7 @@ def _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_attention_kernel(q, arena_k, arena_v, page_table, pos, *,
-                           window: int = 0, interpret: bool = True):
+                           window: int = 0, interpret: bool = False):
     """Pallas paged decode: same contract as ``paged_attention_ref``.
 
     Grid (B, K, max_blocks), pages innermost; ``page_table``/``pos``
@@ -124,8 +124,14 @@ def paged_attention_kernel(q, arena_k, arena_v, page_table, pos, *,
     mb = page_table.shape[1] - 1
     qh = q.reshape(B, K, g, hd)
 
+    # [pages, P, K, hd] -> [pages, P, K * hd] is free (contiguous), and
+    # makes a (P, hd) tile the block's last two dims -- (8, 128)-aligned
+    # for Mosaic -- with kv head h at lane block h
+    arena_k = arena_k.reshape(*arena_k.shape[:2], K * hd)
+    arena_v = arena_v.reshape(*arena_v.shape[:2], K * hd)
+
     def kv_index(b, h, j, pt_ref, pos_ref):
-        return pt_ref[b, j], 0, h, 0
+        return pt_ref[b, j], 0, h
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -133,8 +139,8 @@ def paged_attention_kernel(q, arena_k, arena_v, page_table, pos, *,
         in_specs=[
             pl.BlockSpec((1, 1, g, hd),
                          lambda b, h, j, pt_ref, pos_ref: (b, h, 0, 0)),
-            pl.BlockSpec((1, P, 1, hd), kv_index),
-            pl.BlockSpec((1, P, 1, hd), kv_index),
+            pl.BlockSpec((1, P, hd), kv_index),
+            pl.BlockSpec((1, P, hd), kv_index),
         ],
         out_specs=pl.BlockSpec((1, 1, g, hd),
                                lambda b, h, j, pt_ref, pos_ref: (b, h, 0, 0)),

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from repro import configs
 from repro.configs.base import INPUT_SHAPES, param_count
 from repro.launch.inputspecs import input_specs
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models.sharding import (activation_sharding, batch_shardings,
                                    cache_shardings, params_shardings,
                                    state_shardings)
@@ -225,7 +225,7 @@ def _extrapolate(rec1, rec2, cfg, kind, seq_len=0):
 def run_combo(arch, shape_name, mesh_name, out_dir=None, roofline=True,
               variant="", mesh_shape=None, **kw):
     if mesh_shape:
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+        mesh = make_mesh(mesh_shape, ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=(mesh_name == "pod2"))
     cfg, shape, lowered = lower_combo(arch, shape_name, mesh, **kw)

@@ -1,8 +1,22 @@
-"""Production mesh builders.  Functions, not module constants: importing
-this module must never touch jax device state."""
+"""Mesh builders.  Functions, not module constants: importing this module
+must never touch jax device state."""
 from __future__ import annotations
 
 import jax
+import numpy as np
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """The one mesh builder.  Every axis is ``Auto``: ``jax.make_mesh``
+    defaults to ``Explicit`` axes, which ``with_sharding_constraint``
+    (``models/sharding.py``) rejects.  ``devices`` (default: all) is laid
+    out row-major into ``shape``."""
+    shape, axes = tuple(shape), tuple(axes)
+    types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(shape, axes, axis_types=types)
+    return Mesh(np.asarray(devices).reshape(shape), axes, axis_types=types)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -10,14 +24,13 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (pod=2, data=16, model=16) = 512 chips, 'pod' over DCN."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_dev_mesh(n_devices: int = 0):
     """Small mesh over whatever devices exist (tests / CPU dev box)."""
-    devs = jax.devices()
-    n = n_devices or len(devs)
-    return jax.make_mesh((1, n), ("data", "model"))
+    n = n_devices or len(jax.devices())
+    return make_mesh((1, n), ("data", "model"))
 
 
 def trainer_generator_submeshes(theta: float = 0.5):
@@ -28,8 +41,6 @@ def trainer_generator_submeshes(theta: float = 0.5):
     n_train = max(1, int(n * theta))
     if n - n_train < 1:
         n_train = n - 1
-    from jax.sharding import Mesh
-    import numpy as np
-    t = Mesh(np.array(devs[:n_train]).reshape(1, -1), ("data", "model"))
-    g = Mesh(np.array(devs[n_train:]).reshape(1, -1), ("data", "model"))
+    t = make_mesh((1, n_train), ("data", "model"), devs[:n_train])
+    g = make_mesh((1, n - n_train), ("data", "model"), devs[n_train:])
     return t, g

@@ -3,10 +3,16 @@
     PYTHONPATH=src python -m repro.launch.train \
         --arch starcoder2-3b --smoke --steps 50 --mode async --staleness 1
 
-On a real TPU cluster this builds the production mesh, splits it into
-trainer/generator submeshes (theta fraction, paper Def. 7.4), and runs the
-single-controller loop.  On the CPU dev box (--smoke) it runs the reduced
-config on the local device -- same code path, same executors.
+It builds the executors and channels for one config and runs the
+single-controller loop.  From the command line every in-process executor
+runs on the default device; a caller that hands ``build_controller`` a
+``(trainer, generator)`` submesh pair (``launch/mesh.py``'s
+``trainer_generator_submeshes``, paper Def. 7.4's theta split) places the
+trainer and the generators on disjoint devices, with weights moving
+between them by DDMA resharding (``chip_smoke.py --chips 4`` runs that
+split).  ``--smoke`` runs the reduced config -- same code path, same
+executors.  ``main`` turns on JAX's persistent compilation cache
+(``enable_compile_cache``).
 
 ``--transport proc`` hosts the trainer, every pool generator and (with
 --kl-coef) the frozen reference each in their own spawned process with a
@@ -44,6 +50,9 @@ from repro.core import (AdaptiveStalenessController, CommType,
 from repro.obs import trace as obs_trace
 from repro.rl.data import ArithmeticTasks, VOCAB_SIZE
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
 
 def _parse_addr(s: str):
     host, _, port = s.strip().rpartition(":")
@@ -55,10 +64,33 @@ def _parse_mesh(s: str):
     return tuple(int(p) for p in s.lower().split("x")) if s else ()
 
 
-def build_controller(cfg, args):
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; call from entry points
+    only, never at import.  ``JAX_COMPILATION_CACHE_DIR``, when set, is
+    used as is; otherwise the cache is ``<repo>/.jax_cache`` -- a fixed
+    path, since the path is part of the cache key -- exported so that
+    spawned children share it.  Returns the directory."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO_ROOT, ".jax_cache")
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = path
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def build_controller(cfg, args, meshes=None):
+    """``meshes``: optional ``(trainer_mesh, generator_mesh)``; every
+    executor otherwise runs on the default device.  Submeshes are live
+    device objects, so they need the in-process transport."""
     n_gens = max(1, args.n_generators)
     if args.mode == "sync" or args.sequential:
         assert n_gens == 1, "--n-generators > 1 needs mode=async threads"
+    trn_kw, gen_kw = {}, {}
+    if meshes is not None:
+        if args.transport != "inproc":
+            raise ValueError("submeshes need transport='inproc' (got "
+                             f"{args.transport!r})")
+        trn_kw["mesh"], gen_kw["mesh"] = meshes
     spec = None
     if args.child_devices or args.child_mesh:
         spec = DeviceSpec(device_count=args.child_devices,
@@ -71,7 +103,7 @@ def build_controller(cfg, args):
                       clip_mode=args.clip_mode, kl_coef=args.kl_coef,
                       seed=args.seed, transport=args.transport,
                       device_spec=spec,
-                      address=addrs[0] if addrs else None)
+                      address=addrs[0] if addrs else None, **trn_kw)
     gens, channels = build_generator_pool(
         cfg, trn,
         lambda g: ArithmeticTasks(prompt_len=args.prompt_len,
@@ -81,7 +113,7 @@ def build_controller(cfg, args):
         n_per_prompt=args.n_per_prompt, max_new=args.max_new,
         temperature=args.temp, quantize=args.quantize_generator,
         chunk=args.rollout_chunk, transport=args.transport,
-        device_spec=spec, addresses=addrs[1:1 + n_gens])
+        device_spec=spec, addresses=addrs[1:1 + n_gens], **gen_kw)
     rew = RewardExecutor(n_per_prompt=args.n_per_prompt,
                          leave_one_out=args.rloo)
     executors = gens + [rew, trn]
@@ -143,7 +175,7 @@ def build_controller(cfg, args):
         pool=pool)
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="starcoder2-3b",
                     choices=configs.list_archs() + ["llama31-8b"])
@@ -254,7 +286,12 @@ def main():
                     help="run the async schedule on one thread (debug "
                     "reference; numerically identical, no overlap)")
     ap.add_argument("--out", default="")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def main():
+    args = parse_args()
+    enable_compile_cache()
 
     if args.trace:
         # before any actor spawns: spawned children read the boot flag,

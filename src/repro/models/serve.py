@@ -127,19 +127,29 @@ def init_cache(cfg: ArchConfig, B: int, cache_len: int,
 
 # ----------------------------------------------------------------- prefill -
 
-def _write_seg(seg, kvs, start: int):
-    """Write prefill KVs (stacked [L,B,S,...]) into a ring segment."""
+def _write_seg(seg, kvs):
+    """Write prefill KVs (stacked [L,B,S,...], positions 0..S-1) into a
+    ring segment, position p at slot p % Sc."""
     S = kvs[0].shape[2]
     Sc = seg["slot_pos"].shape[0]
     take = min(S, Sc)
-    pos = jnp.arange(S - take, S) + start
-    slots = pos % Sc
+    pos = jnp.arange(S - take, S)
+    first = (S - take) % Sc
     out = dict(seg)
     keys = ("ckv", "krope") if "ckv" in seg else ("k", "v")
+
+    # a rotation when the whole ring is rewritten, a slice update from
+    # slot 0 otherwise: the TPU compiler aborts on the equivalent scatter
+    # pair over the K and V rings
+    def write(ring, new, axis):
+        if take == Sc:
+            return jnp.roll(new, first, axis=axis)
+        return jax.lax.dynamic_update_slice_in_dim(ring, new, 0, axis=axis)
+
     for key_name, kv in zip(keys, kvs):
-        out[key_name] = seg[key_name].at[:, :, slots].set(
-            kv[:, :, -take:].astype(seg[key_name].dtype))
-    out["slot_pos"] = seg["slot_pos"].at[slots].set(pos.astype(jnp.int32))
+        out[key_name] = write(
+            seg[key_name], kv[:, :, -take:].astype(seg[key_name].dtype), 2)
+    out["slot_pos"] = write(seg["slot_pos"], pos.astype(jnp.int32), 0)
     return out
 
 
@@ -231,7 +241,7 @@ def prefill(params, cfg: ArchConfig, batch, cache_len: int,
     if cfg.family in ("dense", "vlm", "moe"):
         x, kv_segs = _prefill_collect(params, cfg, x, mrope_pos=mrope_pos)
         cache["segments"] = [
-            _write_seg(seg, kvs, start=0)
+            _write_seg(seg, kvs)
             for seg, kvs in zip(cache["segments"], kv_segs)]
         cache["pos"] = jnp.asarray(S + prefix, jnp.int32)
         return bb._logits(params, cfg, x[:, -1]), cache
@@ -265,7 +275,7 @@ def prefill(params, cfg: ArchConfig, batch, cache_len: int,
             lambda *xs: jnp.concatenate(xs, axis=0), *mamba_states)
         kv_k = jnp.stack([kv[0] for kv in attn_kvs])   # [G,B,S,K,hd]
         kv_v = jnp.stack([kv[1] for kv in attn_kvs])
-        cache["attn"] = _write_seg(cache["attn"], (kv_k, kv_v), start=0)
+        cache["attn"] = _write_seg(cache["attn"], (kv_k, kv_v))
         cache["pos"] = jnp.asarray(S, jnp.int32)
         return bb._logits(params, cfg, x[:, -1]), cache
 
@@ -300,7 +310,7 @@ def prefill(params, cfg: ArchConfig, batch, cache_len: int,
             return h, (kv[0], kv[1], ek, ev)
 
         x, (ks, vs, eks, evs) = bb._scan(body, x, params["dec_layers"], cfg)
-        cache["self"] = _write_seg(cache["self"], (ks, vs), start=0)
+        cache["self"] = _write_seg(cache["self"], (ks, vs))
         cache["cross_k"], cache["cross_v"] = eks, evs
         cache["pos"] = jnp.asarray(S, jnp.int32)
         return bb._logits(params, cfg, x[:, -1]), cache

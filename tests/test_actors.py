@@ -228,6 +228,23 @@ def test_spawn_failure_in_child_constructor_propagates():
         spawn_actor(RewardExecutor, n_per_prompt=0, transport="proc")
 
 
+@pytest.mark.parametrize("transport", ["proc", "shm", "socket"])
+def test_local_child_refused_when_parent_holds_tpu(transport, monkeypatch):
+    """A chip belongs to one process: once this process has a TPU
+    backend, a local child actor fails at once instead of waiting out
+    the spawn handshake."""
+    from jax._src import xla_bridge
+    jax.devices()                                # backend initialized
+    assert xla_bridge.backends_are_initialized()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    t0 = time.monotonic()
+    with pytest.raises(RuntimeError, match="already holds the TPU"):
+        spawn_actor(EchoExecutor, "child", transport=transport)
+    assert time.monotonic() - t0 < 5.0
+    h = spawn_actor(EchoExecutor, "here", transport="inproc")
+    assert h.call("ping") == "here"
+
+
 def test_killed_child_raises_actor_died_not_hang():
     h = spawn_actor(EchoExecutor, "victim", transport="proc")
     assert h.call("ping") == "victim"

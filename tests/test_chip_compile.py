@@ -1,0 +1,113 @@
+"""The main-path Pallas kernels compile for a TPU v5e at StarCoder2-3B
+widths (d_model 3072, 24 query / 2 KV heads, head_dim 128, d_ff 12288,
+vocab 49152).
+
+Nothing runs: each kernel is lowered with ``interpret=False`` against a
+*described* v5e topology and handed to the TPU compiler, which refuses
+what Mosaic cannot tile (misaligned blocks, unsupported casts, ops with
+no lowering) exactly as it would on the chip.  The topology is described
+inside a module fixture -- never at import -- because only one process
+at a time may load the TPU library.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.fused_logprob import fused_logprob, fused_logprob_bwd
+from repro.kernels.fused_sample import fused_sample
+from repro.kernels.int8_matmul import int8_matmul
+from repro.kernels.paged_attention import paged_attention_kernel
+
+D_MODEL, D_FF, VOCAB = 3072, 12288, 49152
+N_HEADS, N_KV, HEAD_DIM = 24, 2, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    # a described-device compile is written to the persistent cache but
+    # cannot be read back without a chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler in this environment
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+# 608 = 32 rows x 19 action positions, the launcher's default train batch:
+# the row blocks then tile a longer (padded) array
+@pytest.mark.parametrize("T", [256, 608])
+def test_fused_logprob_fwd_bwd_compile(spec, T):
+    logits = spec((T, VOCAB), jnp.float32)
+    vec = spec((T,), jnp.float32)
+    _compile(functools.partial(fused_logprob, return_stats=True),
+             logits, spec((T,), jnp.int32))
+    _compile(fused_logprob_bwd, logits, spec((T,), jnp.int32), vec, vec, vec)
+
+
+@pytest.mark.parametrize("B,temperature", [(32, 1.0), (32, 0.0),
+                                           (512, 1.0)])
+def test_fused_sample_compiles(spec, B, temperature):
+    _compile(functools.partial(fused_sample, temperature=temperature),
+             spec((B, VOCAB), jnp.float32), spec((2,), jnp.uint32))
+
+
+def test_paged_attention_compiles(spec):
+    B, P, mb = 16, 16, 32
+    n_pages = B * mb
+    arena = spec((n_pages + 1, P, N_KV, HEAD_DIM), jnp.float32)
+    _compile(functools.partial(paged_attention_kernel, window=4096),
+             spec((B, N_HEADS, HEAD_DIM), jnp.float32), arena, arena,
+             spec((B, mb + 1), jnp.int32), spec((B,), jnp.int32))
+
+
+def test_flash_attention_compiles(spec):
+    S = 2048
+    q = spec((1, S, N_HEADS, HEAD_DIM), jnp.float32)
+    kv = spec((1, S, N_KV, HEAD_DIM), jnp.float32)
+    _compile(flash_attention, q, kv, kv)
+
+
+def test_prefill_kv_write_compiles(spec):
+    """``start_rollout`` at full width, one layer: the prefill writes its
+    K and V into the cache as slice updates, which the TPU compiler takes
+    (it aborts the whole process on the scatter pair they replace)."""
+    from repro import configs
+    from repro.models import init_params
+    from repro.rl.rollout import start_rollout
+    cfg = configs.get_config("starcoder2-3b").replace(n_layers=1)
+    params = jax.tree.map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda k: init_params(cfg, k, jnp.float32),
+                       jax.random.PRNGKey(0)))
+    compiled = start_rollout.lower(params, cfg, spec((32, 12), jnp.int32),
+                                   20).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes > 4 * 3e8
+
+
+def test_int8_matmul_compiles(spec):
+    _compile(int8_matmul, spec((256, D_MODEL), jnp.float32),
+             spec((D_MODEL, D_FF), jnp.int8), spec((D_FF,), jnp.float32))

@@ -143,6 +143,62 @@ def test_gumbel_noise_no_counter_wrap():
     assert not jnp.array_equal(na, nb)
 
 
+@pytest.mark.parametrize("temperature", [0.7, 1.0])
+def test_hash_noise_tokens_bit_identical_across_backends(temperature, rng):
+    """hash_uniform's 24 random bits reach float32 through int32 (Mosaic
+    casts int32 -> f32, not uint32 -> f32): the Pallas body
+    (interpreted), the scan fallback and the dense oracle still draw the
+    same tokens, and the uniforms equal the direct uint32 -> float32
+    conversion bit for bit."""
+    from repro.kernels.fused_sample import _mix, fused_sample, hash_uniform
+    logits = jax.random.normal(rng, (24, 1000)) * 2
+    key = jax.random.PRNGKey(11)
+    dense_tok, dense_lp = ref.fused_sample_ref(logits, key, temperature)
+    scan_tok, scan_lp = dispatch._sample_stream_jnp(logits, key, temperature,
+                                                    128)
+    ker_tok, ker_lp = fused_sample(logits, key, temperature=temperature,
+                                   block_b=8, block_v=128, interpret=True)
+    assert jnp.array_equal(scan_tok, dense_tok)
+    assert jnp.array_equal(ker_tok, dense_tok)
+    assert jnp.max(jnp.abs(scan_lp - dense_lp)) < 1e-5
+    assert jnp.max(jnp.abs(ker_lp - dense_lp)) < 1e-5
+
+    rows = jnp.arange(64)[:, None] * jnp.ones((1, 512), jnp.int32)
+    cols = jnp.arange(512)[None, :] * jnp.ones((64, 1), jnp.int32)
+    k0, k1 = jnp.uint32(0xDEADBEEF), jnp.uint32(0x12345678)
+    x = _mix(rows.astype(jnp.uint32) * jnp.uint32(0x9E3779B9) + k0)
+    x = _mix(x + cols.astype(jnp.uint32) * jnp.uint32(0x85EBCA6B) + k1)
+    direct = ((x >> jnp.uint32(8)).astype(jnp.float32) + 0.5) \
+        * (1.0 / (1 << 24))
+    assert jnp.array_equal(hash_uniform(rows, cols, k0, k1), direct)
+
+
+def test_route_compiles_kernels_only_on_tpu(monkeypatch):
+    """auto picks the compiled kernels on a TPU above the size threshold,
+    the streamed jnp path elsewhere; each choice is recorded."""
+    monkeypatch.delenv("REPRO_KERNEL_MODE", raising=False)
+    monkeypatch.delenv("REPRO_PALLAS_COMPILE", raising=False)
+    monkeypatch.delenv("REPRO_KERNEL_MIN_VOCAB", raising=False)
+
+    def route(n, dtype=jnp.float32):
+        return dispatch._route("probe", n, dtype, "REPRO_KERNEL_MIN_VOCAB",
+                               4096)
+
+    before = dispatch.routes_taken().get("probe", {})
+    assert jax.default_backend() == "cpu"
+    assert route(49152) == "jnp"
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert route(49152) == "pallas_compile"
+    assert route(1024) == "jnp"                  # below the threshold
+    assert route(49152, jnp.int32) == "jnp"      # no kernel for the dtype
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
+    assert route(49152) == "pallas_interpret"
+    after = dispatch.routes_taken()["probe"]
+    assert after.get("pallas_compile", 0) - before.get("pallas_compile",
+                                                       0) == 1
+    assert after["jnp"] - before.get("jnp", 0) == 3
+
+
 def test_sample_keys_decorrelate(rng):
     logits = jax.random.normal(rng, (64, 128)) * 0.1   # near-uniform
     t1, _ = dispatch.sample(logits, jax.random.PRNGKey(0), 1.0)

@@ -13,9 +13,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from repro import configs
 from repro.models import init_params, forward_train
+from repro.launch.mesh import make_mesh
 from repro.models.sharding import activation_sharding
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 cfg = configs.get_smoke("deepseek-v3-671b")
 p = init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
 toks = jax.random.randint(jax.random.PRNGKey(1), (4, 32), 0, cfg.vocab)

@@ -40,9 +40,9 @@ def float_eqn_sizes(jaxpr) -> List[int]:
     """All float eqn-output sizes in a jaxpr, recursing into sub-jaxprs
     (scan/while/cond/pallas bodies via ``eqn.params``); ``reshape`` is
     excluded (pure aliasing in XLA, never a materialization)."""
-    import jax
     import jax.numpy as jnp
     import numpy as np
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     sizes = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name != "reshape":
@@ -54,9 +54,9 @@ def float_eqn_sizes(jaxpr) -> List[int]:
                                  else 1)
         for val in eqn.params.values():
             for sub in (val if isinstance(val, (list, tuple)) else [val]):
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, ClosedJaxpr):
                     sizes.extend(float_eqn_sizes(sub.jaxpr))
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, Jaxpr):
                     sizes.extend(float_eqn_sizes(sub))
     return sizes
 
