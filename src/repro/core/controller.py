@@ -72,7 +72,6 @@ from repro.core.genpool import AdaptiveStalenessController, FixedStaleness, \
     GeneratorPool, PoolConfig
 from repro.core.offpolicy import Closed, StalenessBuffer
 from repro.core.supervise import RESPAWNED, RestartPolicy, Supervisor
-from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import IntervalUnion, interval_overlap
 
@@ -373,8 +372,6 @@ class SyncExecutorController:
                        # same clock base as trace events and supervisor
                        # events: one timeline across all three streams
                        t=obs_trace.now())
-        obs_metrics.registry().histogram(
-            "controller.batch_s").observe(step_time)
         self.history.append(metrics)
 
     def _maybe_checkpoint(self, step: int):

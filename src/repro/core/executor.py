@@ -21,6 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import ddma
 from repro.core.aipo import token_logprobs
+from repro.obs import trace as obs_trace
 from repro.rl import data as rl_data
 from repro.rl import rewards as rl_rewards
 from repro.rl.rollout import action_mask, finalize_rollout, rollout_chunk, \
@@ -559,22 +560,27 @@ class TrainerExecutor(Executor):
         return [dict(m) for m in self.metrics_history[-max(0, n):]]
 
     def step(self):
-        scored = self.get_input("completions_with_reward")
-        batch = {
-            "tokens": scored["tokens"],
-            "behavior_logp": scored["behavior_logp"],
-            "advantages": scored["advantages"],
-            "mask": scored["mask"],
-        }
-        if "ref_logp" in scored:
-            batch["ref_logp"] = scored["ref_logp"]
-        with self._on_mesh():
-            self.state, metrics = self._jitted(self.state, batch)
-        metrics = {k: float(v) for k, v in metrics.items()}
-        metrics["mean_reward"] = scored.get("mean_reward", 0.0)
-        self.metrics_history.append(metrics)
-        self.set_output("policy_model", self.state.params)
-        self.curr_step += 1
+        with obs_trace.span("step", "trainer"):
+            with obs_trace.span("assemble", "trainer"):
+                scored = self.get_input("completions_with_reward")
+                batch = {
+                    "tokens": scored["tokens"],
+                    "behavior_logp": scored["behavior_logp"],
+                    "advantages": scored["advantages"],
+                    "mask": scored["mask"],
+                }
+                if "ref_logp" in scored:
+                    batch["ref_logp"] = scored["ref_logp"]
+            with obs_trace.span("dispatch", "trainer"):
+                with self._on_mesh():
+                    self.state, metrics = self._jitted(self.state, batch)
+            with obs_trace.span("readback-wait", "trainer"):
+                metrics = {k: float(v) for k, v in metrics.items()}
+            metrics["mean_reward"] = scored.get("mean_reward", 0.0)
+            self.metrics_history.append(metrics)
+            with obs_trace.span("set-output", "trainer"):
+                self.set_output("policy_model", self.state.params)
+            self.curr_step += 1
         return metrics
 
     def save_checkpoint(self, path: str, step: int):

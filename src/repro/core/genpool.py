@@ -774,66 +774,79 @@ class GeneratorPool:
         claimed = None
         try:
             while not stop.is_set():
-                try:
-                    n = asn.next_for(gen.name)
-                    if n is None and not inflight:
-                        if not self._park(gen, stop):
-                            return
-                        continue
-                    if n is not None and len(inflight) < cfg.max_inflight:
-                        bound = self.bounds.bound()
-                        if gen.call("weight_version") >= max(0, n - bound):
-                            if not asn.start(gen.name, n):
-                                continue  # re-dealt away since the peek
-                            claimed = n
-                            self._fire_chaos("batch", gen, n)
-                            t0 = time.monotonic()
-                            with obs_trace.span("enqueue", "genpool",
-                                                worker=gen.name, batch=n):
-                                gen.call("set_step", n)
-                                gen.call("engine_enqueue", n, bound)
-                            inflight[n] = bound
-                            claimed = None
-                            self.intervals.append((t0, time.monotonic()))
-                            continue
-                        if not inflight:
-                            # nothing decoding: block until the version lands
-                            t0 = time.monotonic()
-                            with obs_trace.span("weight-wait", "genpool",
-                                                worker=gen.name, batch=n):
-                                got = self._drain_one(
-                                    gen, stop, f"weights for batch {n}")
-                            if got is None:
+                # one span per loop iteration: the loop's own bookkeeping
+                # is the tick's self time
+                with obs_trace.span("tick", "genpool"):
+                    try:
+                        n = asn.next_for(gen.name)
+                        if n is None and not inflight:
+                            with obs_trace.span("assignment-wait",
+                                                "genpool"):
+                                parked = self._park(gen, stop)
+                            if not parked:
                                 return
-                            pending_idle += time.monotonic() - t0
                             continue
-                        # rows in flight: poll weights, don't block
-                        self._poll_one(gen)
-                    if not inflight:
-                        continue
-                    t0 = time.monotonic()
-                    with obs_trace.span("engine-round", "genpool",
-                                        worker=gen.name,
-                                        inflight=len(inflight)):
-                        items = gen.call("engine_round",
-                                         self._snapshot_names)
-                    self.intervals.append((t0, time.monotonic()))
-                    for item in items:
-                        item["gen_idle_s"] = pending_idle
-                        pending_idle = 0.0
-                        b = item["batch_index"]
-                        if self._push(gen, stop, item) is None:
+                        if n is not None and len(inflight) < cfg.max_inflight:
+                            bound = self.bounds.bound()
+                            with obs_trace.span("poll", "genpool"):
+                                ready = gen.call("weight_version") \
+                                    >= max(0, n - bound)
+                            if ready:
+                                if not asn.start(gen.name, n):
+                                    continue  # re-dealt away since the peek
+                                claimed = n
+                                self._fire_chaos("batch", gen, n)
+                                t0 = time.monotonic()
+                                with obs_trace.span("enqueue", "genpool",
+                                                    worker=gen.name, batch=n):
+                                    gen.call("set_step", n)
+                                    gen.call("engine_enqueue", n, bound)
+                                inflight[n] = bound
+                                claimed = None
+                                self.intervals.append((t0, time.monotonic()))
+                                continue
+                            if not inflight:
+                                # nothing decoding: block until the version
+                                # lands
+                                t0 = time.monotonic()
+                                with obs_trace.span("weight-wait", "genpool",
+                                                    worker=gen.name, batch=n):
+                                    got = self._drain_one(
+                                        gen, stop, f"weights for batch {n}")
+                                if got is None:
+                                    return
+                                pending_idle += time.monotonic() - t0
+                                continue
+                            # rows in flight: poll weights, don't block
+                            with obs_trace.span("poll", "genpool"):
+                                self._poll_one(gen)
+                        if not inflight:
+                            continue
+                        t0 = time.monotonic()
+                        with obs_trace.span("engine-round", "genpool",
+                                            worker=gen.name,
+                                            inflight=len(inflight)):
+                            items = gen.call("engine_round",
+                                             self._snapshot_names)
+                        self.intervals.append((t0, time.monotonic()))
+                        for item in items:
+                            item["gen_idle_s"] = pending_idle
+                            pending_idle = 0.0
+                            b = item["batch_index"]
+                            with obs_trace.span("push", "genpool"):
+                                pushed = self._push(gen, stop, item)
+                            if pushed is None:
+                                return
+                            asn.finish(gen.name, b)
+                            inflight.pop(b, None)
+                    except (ActorDied, TimeoutError) as e:
+                        if not self._recover(gen, None, e):
                             return
-                        asn.finish(gen.name, b)
-                        inflight.pop(b, None)
-                except (ActorDied, TimeoutError) as e:
-                    if not self._recover(gen, None, e):
-                        return
-                    # respawned: the supervisor's readmit hook already
-                    # rebuilt the engine and re-enqueued `inflight`
-                    if claimed is not None:
-                        asn.requeue(gen.name, claimed)  # died pre-enqueue
-                        claimed = None
+                        # respawned: the supervisor's readmit hook already
+                        # rebuilt the engine and re-enqueued `inflight`
+                        if claimed is not None:
+                            asn.requeue(gen.name, claimed)  # died pre-enqueue
+                            claimed = None
         finally:
             try:    # drop parked pool state + live rows on the way out
                 gen.call("engine_abort")

@@ -25,6 +25,12 @@ strictly decreasing, recovery in under a second -- is a statement about
     maps child timestamps onto the parent's epoch.  Span context rides
     the RPC frames as flow ids (``flow_start``/``flow_end``), so
     Perfetto draws the caller->callee arrow across process rows.
+  * **on the profiler's clock** -- while enabled, every live span also
+    opens a ``jax.profiler.TraceAnnotation`` named ``repro:{cat}.{name}``
+    (``repro:engine.decode-round``), so a ``jax.profiler`` trace taken
+    meanwhile holds the program's own phases on the device trace's
+    clock.  ``complete()`` records are already-timed intervals and stay
+    in the ring buffer only.
   * ``to_chrome``/``export`` -- Chrome trace-event / Perfetto JSON: one
     pid row per actor process, one tid row per thread, complete ("X")
     spans, instant ("i") events and flow ("s"/"f") arrows, with the
@@ -47,6 +53,9 @@ from typing import Any, Dict, List, Optional, Tuple
 ENV_FLAG = "REPRO_TRACE"
 ENV_BUFFER = "REPRO_TRACE_BUFFER"
 DEFAULT_BUFFER = 1 << 18
+
+#: prefix of the profiler annotations the live spans open
+ANNOTATION_PREFIX = "repro:"
 
 #: the process-wide trace epoch: every timestamp this module (and the
 #: supervisor/controller bookkeeping built on it) records is
@@ -90,9 +99,10 @@ NOOP_SPAN = _NoopSpan()
 
 
 class _Span:
-    """A live span: records one complete ("X") event on exit."""
+    """A live span: records one complete ("X") event on exit, and holds a
+    profiler annotation of the same phase open while it runs."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Optional[dict]):
@@ -112,11 +122,15 @@ class _Span:
         return self
 
     def __enter__(self):
+        label = f"{self.cat}.{self.name}" if self.cat else self.name
+        self._ann = self._tracer._annotation(ANNOTATION_PREFIX + label)
+        self._ann.__enter__()
         self._t0 = now()
         self._tracer._stack().append(self.name)
         return self
 
     def __exit__(self, et, ev, tb):
+        self._ann.__exit__(et, ev, tb)
         stack = self._tracer._stack()
         if stack:
             stack.pop()
@@ -136,6 +150,8 @@ class Tracer:
     counts them, approximately: the counter itself is unlocked)."""
 
     def __init__(self, proc: str, capacity: int = 0):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         self.proc = proc
         cap = capacity or int(os.environ.get(ENV_BUFFER, DEFAULT_BUFFER))
         self._buf: collections.deque = collections.deque(maxlen=cap)

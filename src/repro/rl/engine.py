@@ -47,6 +47,7 @@ import numpy as np
 from repro.core.offpolicy import PartialRolloutCache
 from repro.models.paging import PagePool, RadixCache, paged_blocks, \
     plan_admission, release_plan
+from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.rl import data as rl_data
 from repro.rl import rewards as rl_rewards
@@ -210,6 +211,13 @@ class RolloutEngine:
             "admission_backpressure": 0, "radix_hits": 0,
             "radix_misses": 0, "prefix_tokens_reused": 0,
         }
+        # process-wide round counters: rounds that dispatched a chunk, and
+        # the rows live at each such dispatch (slot occupancy is their
+        # ratio over the ``engine.slots`` gauge)
+        reg = obs_metrics.registry()
+        self._rounds = reg.counter("engine.rounds")
+        self._live_row_rounds = reg.counter("engine.live_row_rounds")
+        reg.gauge("engine.slots").set(self.max_running_rows)
 
     # ----------------------------------------------------------- admission --
 
@@ -325,12 +333,13 @@ class RolloutEngine:
         "busy_s"}``)."""
         ex = self.executor
         t0 = time.monotonic()
-        state = self.cache.get(self._rid) if self._rid is not None \
-            else start_row_pool(ex.cfg, self.max_running_rows,
-                                self.total_len, self.prompt_len,
-                                kv_layout=self.kv_layout,
-                                kv_page_size=self.kv_page_size,
-                                kv_pages=self.kv_pages)
+        with obs_trace.span("park", "engine"):
+            state = self.cache.get(self._rid) if self._rid is not None \
+                else start_row_pool(ex.cfg, self.max_running_rows,
+                                    self.total_len, self.prompt_len,
+                                    kv_layout=self.kv_layout,
+                                    kv_page_size=self.kv_page_size,
+                                    kv_pages=self.kv_pages)
         self._rid = None
         with obs_trace.span("admit", "engine", waiting=len(self.waiting),
                             free=self.slots.free_count):
@@ -345,6 +354,8 @@ class RolloutEngine:
                 state = rollout_rows_chunk(ex.params, ex.cfg, state, sub,
                                            n_steps=self.chunk,
                                            temperature=ex.temperature)
+            self._rounds.inc()
+            self._live_row_rounds.inc(len(self.tickets))
             for t in self.tickets.values():
                 t.chunks_done += 1
             state, emitted = self._harvest(state)
@@ -353,7 +364,8 @@ class RolloutEngine:
                               pages_in_use=self.page_pool.pages_in_use,
                               pages_total=self.page_pool.n_pages,
                               radix_nodes=len(self.radix))
-        self._rid = self.cache.put(state)
+        with obs_trace.span("park", "engine"):
+            self._rid = self.cache.put(state)
         self._busy_s += time.monotonic() - t0
         return emitted
 
@@ -364,7 +376,8 @@ class RolloutEngine:
         release the row's page refs and remap its table to the trash
         page (``release_row``), so the state changes here."""
         ex = self.executor
-        done = np.asarray(state.done)
+        with obs_trace.span("readback-wait", "engine"):
+            done = np.asarray(state.done)
         ready = [s for s, t in self.tickets.items()
                  if done[s] or t.chunks_done >= t.max_chunks]
         if not ready:
@@ -372,8 +385,9 @@ class RolloutEngine:
         emitted = []
         keep = self.prompt_len + ex.max_new
         with obs_trace.span("harvest", "engine", rows=len(ready)):
-            tokens_np = np.asarray(state.tokens)
-            blp_np = np.asarray(state.behavior_logp)
+            with obs_trace.span("readback-wait", "engine"):
+                tokens_np = np.asarray(state.tokens)
+                blp_np = np.asarray(state.behavior_logp)
             for s in ready:
                 t = self.tickets.pop(s)
                 self.slots.release(s)

@@ -23,6 +23,7 @@ from repro.core.aipo import token_logprobs
 from repro.core.executor import GeneratorExecutor
 from repro.models import decode_step, forward_train, init_params
 from repro.models.serve import SlotPool, assert_engine_cache
+from repro.obs import metrics as obs_metrics
 from repro.rl.data import PAD, ArithmeticTasks
 from repro.rl.engine import GroupLedger, RolloutEngine
 from repro.rl.rollout import (admit_row, rollout_rows_chunk, start_rollout,
@@ -179,6 +180,30 @@ def test_engine_emits_group_complete_batches_with_exact_mu():
     adv = np.asarray(rew.get_output("completions_with_reward")["advantages"])
     np.testing.assert_allclose(
         adv, out["group_advantages"][:, None] * mask)
+
+
+def test_engine_counters_advance_once_per_dispatched_round():
+    """``engine.rounds`` counts each round that dispatched a chunk and
+    ``engine.live_row_rounds`` the rows live at that dispatch; the
+    ``engine.slots`` gauge holds the slot count.  A round with no live
+    row dispatches nothing and counts nothing."""
+    reg = obs_metrics.registry()
+    ex = _executor(chunk=2, max_new=4)           # 2 chunks per row
+    ex.engine_configure(max_running_rows=8)
+    rounds = reg.counter("engine.rounds")
+    live = reg.counter("engine.live_row_rounds")
+    assert reg.gauge("engine.slots").value == 8
+    r0, l0 = rounds.value, live.value
+    ex.engine_round(["completions"])             # nothing enqueued
+    assert (rounds.value, live.value) == (r0, l0)
+    ex.engine_enqueue(0, bound=0)                # 4 rows
+    ex.engine_round(["completions"])
+    assert (rounds.value, live.value) == (r0 + 1, l0 + 4)
+    items = ex.engine_round(["completions"])     # last chunk: harvested
+    assert [it["batch_index"] for it in items] == [0]
+    assert (rounds.value, live.value) == (r0 + 2, l0 + 8)
+    ex.engine_round(["completions"])             # empty again
+    assert (rounds.value, live.value) == (r0 + 2, l0 + 8)
 
 
 def test_engine_abort_mid_decode_releases_everything():

@@ -61,6 +61,89 @@ def test_disabled_tracer_is_shared_noop():
     assert obs_trace.tracer() is None
 
 
+class _CountingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records each
+    label it is opened with."""
+
+    made: list = []
+
+    def __init__(self, name):
+        self.made.append(name)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, et, ev, tb):
+        return False
+
+
+def test_disabled_span_opens_no_profiler_annotation(monkeypatch):
+    import jax.profiler
+    made = _CountingAnnotation.made = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
+    assert not obs_trace.enabled()
+    sp = obs_trace.span("decode-round", "engine", rows=3)
+    assert sp is obs_trace.NOOP_SPAN
+    with sp:
+        pass
+    assert made == []
+    # an enabled tracer names each live span's annotation by category
+    t = obs_trace.Tracer("probe")
+    with t.span("decode-round", "engine"):
+        with t.span("bare"):
+            pass
+    assert made == ["repro:engine.decode-round", "repro:bare"]
+
+
+def test_enabled_spans_land_on_their_thread_in_a_profiler_trace(traced,
+                                                                tmp_path):
+    """Nested spans opened on two threads while ``jax.profiler`` traces
+    show up as ``repro:`` events, each thread's on a host line of its
+    own, the inner one inside the outer one."""
+    import glob
+    import threading
+
+    import jax
+    from jax.profiler import ProfileData
+
+    def work(tag):
+        with obs_trace.span("outer", tag):
+            with obs_trace.span("inner-wait", tag):
+                time.sleep(0.005)
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        th = threading.Thread(target=work, args=("worker",))
+        th.start()
+        work("main")
+        th.join(timeout=10)
+    finally:
+        jax.profiler.stop_trace()
+    assert not th.is_alive()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    lines = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            evs = {ev.name: (ev.start_ns, ev.start_ns + ev.duration_ns)
+                   for ev in line.events if ev.name.startswith("repro:")}
+            if evs:
+                lines[(plane.name, i)] = evs
+    by_names = sorted(sorted(evs) for evs in lines.values())
+    assert by_names == [["repro:main.inner-wait", "repro:main.outer"],
+                        ["repro:worker.inner-wait", "repro:worker.outer"]]
+    for evs in lines.values():
+        (o0, o1), = [v for k, v in evs.items() if k.endswith(".outer")]
+        (i0, i1), = [v for k, v in evs.items() if k.endswith("-wait")]
+        assert o0 <= i0 < i1 <= o1
+        assert i1 - i0 >= 5e6                     # the sleep, in ns
+    # the ring buffer still records every span
+    assert sorted(e[3] for e in traced.events() if e[2] == "X") == \
+        ["inner-wait", "inner-wait", "outer", "outer"]
+
+
 def test_span_records_complete_event_with_nesting(traced):
     with traced.span("outer", "t"):
         assert traced.current_span() == "outer"
