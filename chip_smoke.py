@@ -169,7 +169,8 @@ def check_routes(name, on_tpu):
     from repro.kernels import dispatch
     routes = dispatch.routes_taken()
     print(f"[{name}] kernel routes (traces per backend): "
-          f"{json.dumps(routes, sort_keys=True)}")
+          f"{json.dumps(routes, sort_keys=True)}; paged-attention pages "
+          f"per block (P, K, hd, max_blocks): {dispatch.paged_block_pages()}")
     if on_tpu:
         for path in HOT_PATHS[name]:
             check(set(routes.get(path, {})) == {"pallas_compile"},

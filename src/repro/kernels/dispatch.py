@@ -50,6 +50,9 @@ _PALLAS_DTYPES = (jnp.float32, jnp.bfloat16, jnp.float16)
 # read by routes_taken (generator and trainer threads trace concurrently)
 _ROUTES: collections.Counter = collections.Counter()
 _ROUTES_LOCK = threading.Lock()
+# (P, K, hd, max_blocks) -> pages per DMA block of each paged-attention
+# shape staged on a kernel route, read by paged_block_pages
+_BLOCK_PAGES: dict = {}
 
 
 def _env_int(name: str, default: int) -> int:
@@ -117,6 +120,13 @@ def routes_taken() -> dict:
         for (path, backend), n in _ROUTES.items():
             out.setdefault(path, {})[backend] = n
     return out
+
+
+def paged_block_pages() -> dict:
+    """``{(P, K, hd, max_blocks): pages per block}`` of each paged
+    attention shape staged on a kernel route so far in this process."""
+    with _ROUTES_LOCK:
+        return dict(_BLOCK_PAGES)
 
 
 # ------------------------------------------------------- token logprob ---
@@ -362,22 +372,30 @@ def paged_attention(q, arena_k, arena_v, page_table, pos, *, window: int = 0):
     """Paged decode attention: one query per row against the row's page
     table over a shared KV arena (``models/paging.py`` layout).
 
-    q: [B, H, hd]; arena_[kv]: [n_pages + 1, P, K, hd]; page_table:
+    q: [B, H, hd]; arena_[kv]: [n_pages + 1, P, K * hd] (a
+    ``[n_pages + 1, P, K, hd]`` arena is viewed so); page_table:
     [B, max_blocks + 1] int32; pos: [B] int32 -> [B, H, hd].  Routing
     follows the attention seq-len threshold on the row's *logical*
     length ``max_blocks * P`` (what one program actually streams); the
     jnp route is the gather reference that is bitwise-equal to dense
-    ``gqa_decode``, the kernel route streams pages via scalar-prefetched
-    index maps without materializing the gather.
+    ``gqa_decode``, the kernel route DMAs each row's pages from the table
+    without materializing the gather.
     """
-    from repro.kernels.paged_attention import (paged_attention_kernel,
+    from repro.kernels.paged_attention import (block_pages,
+                                               paged_attention_kernel,
                                                paged_attention_ref)
-    S = (page_table.shape[1] - 1) * arena_k.shape[1]
-    backend = _route("paged_attention", S, q.dtype, "REPRO_KERNEL_MIN_SEQ",
-                     512)
+    if arena_k.ndim == 4:     # as bench/record_trace.py holds it
+        arena_k = arena_k.reshape(*arena_k.shape[:2], -1)
+        arena_v = arena_v.reshape(*arena_v.shape[:2], -1)
+    P, hd, mb = arena_k.shape[1], q.shape[-1], page_table.shape[1] - 1
+    backend = _route("paged_attention", mb * P, q.dtype,
+                     "REPRO_KERNEL_MIN_SEQ", 512)
     if backend == "jnp":
         return paged_attention_ref(q, arena_k, arena_v, page_table, pos,
                                    window=window)
+    shape = (P, arena_k.shape[2] // hd, hd, mb)
+    with _ROUTES_LOCK:
+        _BLOCK_PAGES[shape] = block_pages(*shape, arena_k.dtype.itemsize)
     return _per_device(functools.partial(
         paged_attention_kernel, window=window,
         interpret=backend != "pallas_compile"))(

@@ -231,7 +231,7 @@ def gqa_decode_paged(p, x, arena_k, arena_v, page_table, pos, cfg, *,
                      window: int = 0):
     """One-token decode against a paged KV arena (``models/paging.py``).
 
-    x: [B, 1, D]; arena_[kv]: [n_pages + 1, P, K, hd] (last page is the
+    x: [B, 1, D]; arena_[kv]: [n_pages + 1, P, K * hd] (last page is the
     trash page); page_table: [B, max_blocks + 1] int32 with the last
     entry always trash; pos: [B] decode cursor per row.
 
@@ -259,8 +259,10 @@ def gqa_decode_paged(p, x, arena_k, arena_v, page_table, pos, cfg, *,
     # distinct live rows write distinct private pages (shared radix pages
     # cover only the block-aligned prompt prefix, below every decode
     # cursor); trash-page collisions between done rows are unread garbage
-    arena_k = arena_k.at[pg, off].set(k[:, 0].astype(arena_k.dtype))
-    arena_v = arena_v.at[pg, off].set(v[:, 0].astype(arena_v.dtype))
+    arena_k = arena_k.at[pg, off].set(
+        k.reshape(B, -1).astype(arena_k.dtype))
+    arena_v = arena_v.at[pg, off].set(
+        v.reshape(B, -1).astype(arena_v.dtype))
     y = dispatch.paged_attention(q[:, 0], arena_k, arena_v, page_table, pos,
                                  window=window)
     y = y.reshape(B, 1, H * hd) @ p["wo"]

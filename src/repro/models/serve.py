@@ -38,13 +38,15 @@ def _kv_seg(cfg, n_layers, B, Sc, dtype):
 
 def _kv_seg_paged(cfg, n_layers, n_pages, page_size, dtype):
     """Paged arena for one segment: ``n_pages`` allocatable pages of
-    ``page_size`` KV slots plus the trash page at index ``n_pages``.
-    No ``slot_pos``: validity is per-row (col <= row cursor), carried by
-    the page table + ``pos`` vector at the cache top level."""
+    ``page_size`` KV slots plus the trash page at index ``n_pages``, each
+    slot a token's K kv heads side by side (``[K * hd]``, the layout the
+    paged kernel reads).  No ``slot_pos``: validity is per-row (col <= row
+    cursor), carried by the page table + ``pos`` vector at the cache top
+    level."""
     K, hd = cfg.n_kv_heads, cfg.hd
     return {
-        "k": jnp.zeros((n_layers, n_pages + 1, page_size, K, hd), dtype),
-        "v": jnp.zeros((n_layers, n_pages + 1, page_size, K, hd), dtype),
+        "k": jnp.zeros((n_layers, n_pages + 1, page_size, K * hd), dtype),
+        "v": jnp.zeros((n_layers, n_pages + 1, page_size, K * hd), dtype),
     }
 
 

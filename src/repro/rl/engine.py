@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.offpolicy import PartialRolloutCache
+from repro.kernels import dispatch
 from repro.models.paging import PagePool, RadixCache, paged_blocks, \
     plan_admission, release_plan
 from repro.obs import metrics as obs_metrics
@@ -360,6 +361,7 @@ class RolloutEngine:
                 t.chunks_done += 1
             state, emitted = self._harvest(state)
         if self.page_pool is not None:
+            self._publish_block_pages()
             obs_trace.instant("pages", "engine",
                               pages_in_use=self.page_pool.pages_in_use,
                               pages_total=self.page_pool.n_pages,
@@ -368,6 +370,17 @@ class RolloutEngine:
             self._rid = self.cache.put(state)
         self._busy_s += time.monotonic() - t0
         return emitted
+
+    def _publish_block_pages(self):
+        """Set the ``kernels.paged_attention.block_pages`` gauge to the
+        pages per block the paged kernel streams at this pool's shape,
+        once a decode chunk has staged it there."""
+        cfg = self.executor.cfg
+        ppb = dispatch.paged_block_pages().get(
+            (self.kv_page_size, cfg.n_kv_heads, cfg.hd, self._max_blocks))
+        if ppb is not None:
+            obs_metrics.registry().gauge(
+                "kernels.paged_attention.block_pages").set(ppb)
 
     def _harvest(self, state):
         """Free every finished row (EOS, or per-row budget exhausted)

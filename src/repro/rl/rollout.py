@@ -264,11 +264,9 @@ def admit_row_paged(params, cfg, state: RolloutState, prompt, pages_row,
     assert n_cached == ncb * P and n_cached < Sp, (n_cached, P, Sp)
     prefix_kvs = []
     for seg in cache["segments"]:
-        L = seg["k"].shape[0]
-        tail = seg["k"].shape[3:]
-        prefix_kvs.append(
-            (seg["k"][:, pages_row[:ncb]].reshape(L, 1, n_cached, *tail),
-             seg["v"][:, pages_row[:ncb]].reshape(L, 1, n_cached, *tail)))
+        shape = (seg["k"].shape[0], 1, n_cached, cfg.n_kv_heads, cfg.hd)
+        prefix_kvs.append((seg["k"][:, pages_row[:ncb]].reshape(shape),
+                           seg["v"][:, pages_row[:ncb]].reshape(shape)))
     x = bb._embed(params, cfg, prompt[:, n_cached:])
     x, kv_segs = _extend_collect(params, cfg, x, prefix_kvs, n_cached)
     last_logits = bb._logits(params, cfg, x[:, -1])
@@ -278,9 +276,12 @@ def admit_row_paged(params, cfg, state: RolloutState, prompt, pages_row,
     off = pos_sfx % P
     new_segs = []
     for seg, (ks, vs) in zip(cache["segments"], kv_segs):
+        L = ks.shape[0]
         new_segs.append({
-            "k": seg["k"].at[:, pg, off].set(ks[:, 0].astype(seg["k"].dtype)),
-            "v": seg["v"].at[:, pg, off].set(vs[:, 0].astype(seg["v"].dtype)),
+            "k": seg["k"].at[:, pg, off].set(
+                ks[:, 0].reshape(L, Sp - n_cached, -1).astype(seg["k"].dtype)),
+            "v": seg["v"].at[:, pg, off].set(
+                vs[:, 0].reshape(L, Sp - n_cached, -1).astype(seg["v"].dtype)),
         })
     row_tokens = jnp.zeros((T,), jnp.int32).at[:Sp].set(prompt[0])
     new_cache = {

@@ -10,6 +10,7 @@ inside a module fixture -- never at import -- because only one process
 at a time may load the TPU library.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -75,13 +76,72 @@ def test_fused_sample_compiles(spec, B, temperature):
              spec((B, VOCAB), jnp.float32), spec((2,), jnp.uint32))
 
 
-def test_paged_attention_compiles(spec):
-    B, P, mb = 16, 16, 32
+def _custom_call_signature(text):
+    """((dtype, rank) of the result, of each operand) of the one
+    ``tpu_custom_call`` in a compiled module, in the form of
+    ``bench.kernels.SIGNATURES``."""
+    from bench import kernels
+    (line,) = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    arrays = lambda s: tuple((t, len(d.split(","))) for t, d in
+                             kernels._ARRAY.findall(s))
+    result = line.split("=", 1)[1].split("custom-call(")[0]
+    operands = re.search(r"operand_layout_constraints=\{(.*?\})\}",
+                         line).group(1)
+    return arrays(result), arrays(operands)
+
+
+def _arena_relayouts(text, n_pages):
+    """Reshapes and copies of an arena-sized array in a compiled module."""
+    return [ln.strip() for ln in text.splitlines()
+            if re.search(rf"\[{n_pages + 1},\d", ln)
+            and re.search(r"\s(reshape|copy|transpose)\(", ln)]
+
+
+# StarCoder2-3B's decode cell (24 / 2 heads, windowed), and 8 kv heads
+@pytest.mark.parametrize("n_heads,n_kv,window", [(N_HEADS, N_KV, 4096),
+                                                 (64, 8, 0)])
+def test_paged_attention_compiles(spec, n_heads, n_kv, window):
+    """The kernel compiles at the cell's shapes, its custom call keeps the
+    signature the benchmark tells it by, and the arena reaches it in
+    the layout it is stored in: no relayout of the arena around it."""
+    from bench import kernels
+    B, P, mb = 16, 16, 33
     n_pages = B * mb
-    arena = spec((n_pages + 1, P, N_KV, HEAD_DIM), jnp.float32)
-    _compile(functools.partial(paged_attention_kernel, window=4096),
-             spec((B, N_HEADS, HEAD_DIM), jnp.float32), arena, arena,
-             spec((B, mb + 1), jnp.int32), spec((B,), jnp.int32))
+    arena = spec((n_pages + 1, P, n_kv * HEAD_DIM), jnp.float32)
+    text = _compile(functools.partial(paged_attention_kernel, window=window),
+                    spec((B, n_heads, HEAD_DIM), jnp.float32), arena, arena,
+                    spec((B, mb + 1), jnp.int32),
+                    spec((B,), jnp.int32)).as_text()
+    assert _custom_call_signature(text) in \
+        kernels.SIGNATURES["paged_attention"]
+    assert _arena_relayouts(text, n_pages) == []
+
+
+def test_paged_decode_chunk_compiles(spec, monkeypatch):
+    """The engine's decode chunk at the cell's shapes (16 slots of 12 +
+    512 positions, pages of 16, one layer) on the compiled kernel route:
+    the per-step KV write lands in the arena's stored layout, and no
+    arena-sized relayout is left in the program."""
+    from repro import configs
+    from repro.models import init_params
+    from repro.rl.rollout import rollout_rows_chunk, start_row_pool
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "compile")
+    cfg = configs.get_config("starcoder2-3b").replace(n_layers=1)
+    as_spec = lambda tree: jax.tree.map(lambda a: spec(a.shape, a.dtype),
+                                        tree)
+    params = as_spec(jax.eval_shape(
+        lambda k: init_params(cfg, k, jnp.float32), jax.random.PRNGKey(0)))
+    state = as_spec(jax.eval_shape(lambda: start_row_pool(
+        cfg, 16, 12 + 512, 12, kv_layout="paged", kv_page_size=16)))
+    n_pages = 16 * 33
+    assert state.cache["segments"][0]["k"].shape == \
+        (1, n_pages + 1, 16, N_KV * HEAD_DIM)
+    text = rollout_rows_chunk.lower(params, cfg, state,
+                                    spec((2,), jnp.uint32), n_steps=16
+                                    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert _arena_relayouts(text, n_pages) == []
 
 
 def test_flash_attention_compiles(spec):

@@ -19,7 +19,9 @@ import jax.numpy as jnp
 
 from _hypothesis_compat import given, settings, st
 from repro.kernels import dispatch
-from repro.kernels.paged_attention import (paged_attention_kernel,
+from repro.kernels import paged_attention as pa
+from repro.kernels.paged_attention import (block_pages,
+                                           paged_attention_kernel,
                                            paged_attention_ref)
 from repro.models import init_params
 from repro.models.paging import (PagePlan, PagePool, RadixCache,
@@ -391,28 +393,56 @@ def test_paged_matches_dense_property(order):
 
 # ------------------------------------------------------- pallas kernel ---
 
-@pytest.fixture
-def arena_problem():
-    key = jax.random.PRNGKey(0)
-    B, H, K, hd, P, mb, n_pages = 3, 4, 2, 16, 5, 4, 16
-    k1, k2, k3 = jax.random.split(key, 3)
-    q = jax.random.normal(k1, (B, H, hd), jnp.float32)
-    ak = jax.random.normal(k2, (n_pages + 1, P, K, hd), jnp.float32)
-    av = jax.random.normal(k3, (n_pages + 1, P, K, hd), jnp.float32)
+def _arena(pos, K=2, g=2, hd=16, P=5, mb=4, n_pages=16):
+    """A decode problem over an arena in its stored layout
+    ``[n_pages + 1, P, K * hd]``, one row per cursor in ``pos``."""
+    B = len(pos)
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(k1, (B, K * g, hd), jnp.float32)
+    ak = jax.random.normal(k2, (n_pages + 1, P, K * hd), jnp.float32)
+    av = jax.random.normal(k3, (n_pages + 1, P, K * hd), jnp.float32)
     pt = jnp.asarray(np.random.RandomState(0).randint(
         0, n_pages, (B, mb + 1)), jnp.int32)
-    pos = jnp.asarray([3, 11, 19], jnp.int32)
-    return q, ak, av, pt, pos
+    return q, ak, av, pt, jnp.asarray(pos, jnp.int32)
 
 
-@pytest.mark.parametrize("window", [0, 6])
-def test_paged_attention_kernel_matches_ref(arena_problem, window):
-    q, ak, av, pt, pos = arena_problem
+@pytest.fixture
+def arena_problem():
+    return _arena([3, 11, 19])
+
+
+MB, PPB = 7, 3     # rows of 7 pages, DMA blocks of 3 (the last one ragged)
+
+
+@pytest.mark.parametrize("window", [0, 6, MB * 16 + 10])
+@pytest.mark.parametrize("P", [5, 16])
+@pytest.mark.parametrize("K,g", [(2, 12), (8, 8), (8, 5), (1, 4)])
+def test_paged_attention_kernel_matches_ref(monkeypatch, K, g, P, window):
+    """Rows of every trip count in one call: cursors at 0, either side of
+    a page edge and of a block edge, a row starting past its first block
+    under the window, the last column, and the clamp ``max_blocks * P``
+    (every column)."""
+    hd = 16
+    monkeypatch.setattr(pa, "_BLOCK_VMEM_BYTES", 4 * PPB * P * K * hd * 4)
+    assert block_pages(P, K, hd, MB) == PPB
+    pos = [0, P - 1, P, PPB * P - 1, PPB * P, 2 * PPB * P + 2,
+           MB * P - 1, MB * P]
+    q, ak, av, pt, pos = _arena(pos, K, g, hd, P, MB, n_pages=40)
     ref = paged_attention_ref(q, ak, av, pt, pos, window=window)
     ker = paged_attention_kernel(q, ak, av, pt, pos, window=window,
                                  interpret=True)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("P,K,hd,mb,ppb", [
+    (16, 2, 128, 33, 16),     # StarCoder2-3B: 16 KB pages
+    (16, 8, 128, 33, 4),      # 8 kv heads: 64 KB pages
+    (16, 2, 128, 10, 10),     # a short row: one block
+    (64, 8, 256, 33, 1),      # a page over the budget still streams
+])
+def test_block_pages_from_shapes(P, K, hd, mb, ppb):
+    assert block_pages(P, K, hd, mb) == ppb
 
 
 def test_paged_attention_kernel_pos_zero_edge(arena_problem):
@@ -437,6 +467,12 @@ def test_paged_attention_dispatch_routes(arena_problem, monkeypatch):
     ker = dispatch.paged_attention(q, ak, av, pt, pos)
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+    # the kernel route records its block: pages 5 x 2 heads x 16 -> all 4
+    assert dispatch.paged_block_pages()[(5, 2, 16, 4)] == 4
+    # an arena held as [pages, P, K, hd] is the same arena
+    ker4 = dispatch.paged_attention(q, ak.reshape(17, 5, 2, 16),
+                                    av.reshape(17, 5, 2, 16), pt, pos)
+    np.testing.assert_array_equal(np.asarray(ker4), np.asarray(ker))
 
 
 def test_paged_pool_decode_under_interpret_kernel(monkeypatch):
@@ -515,6 +551,28 @@ def test_engine_paged_exact_mu_and_prefix_reuse():
 
     ex.engine_abort()
     assert ex.engine_stats()["pages_in_use"] == 0   # radix cleared too
+
+
+def test_engine_paged_kernel_sets_block_pages_gauge(monkeypatch):
+    """A decode chunk that stages the paged kernel sets the
+    ``kernels.paged_attention.block_pages`` gauge to the block the kernel
+    streams at the engine's pool shape."""
+    from repro.obs import metrics as obs_metrics
+    gauge = obs_metrics.registry().gauge("kernels.paged_attention.block_pages")
+    gauge.set(-1)
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
+    monkeypatch.setenv("REPRO_KERNEL_MIN_SEQ", "1")
+    # 4-slot pages of 2 heads x 16: two pages a block, under the row's 3
+    monkeypatch.setattr(pa, "_BLOCK_VMEM_BYTES", 4 * 2 * 4 * 2 * 16 * 4)
+    jax.clear_caches()          # stage the chunk afresh under this route
+    ex = _paged_executor()
+    ex.engine_enqueue(0, bound=1)
+    assert len(_drain(ex, 1)) == 1
+    engine = ex._engine
+    assert engine._max_blocks == 3
+    assert gauge.value == 2
+    assert dispatch.paged_block_pages()[(4, 2, 16, 3)] == 2
+    jax.clear_caches()
 
 
 def test_engine_paged_tiny_arena_backpressures_and_completes():
