@@ -27,8 +27,8 @@ NOTES = {
     "axis, overlap collectives with compute, or replicate small params",
     ("collective_s", "decode"): "TP all-reduces per token: fuse, or "
     "shrink mp (paper Sec. 4.3)",
-    ("compute_s", "decode"): "MoE gathered-dispatch wastes expert flops: "
-    "expert-parallel all-to-all (moe_mode=ep)",
+    ("compute_s", "decode"): "MoE experts computed where the tokens are: "
+    "split them over the model axis (moe_mode=ep_shmap)",
     ("compute_s", "prefill"): "attention flops: windowed/blocksparse "
     "variants",
 }

@@ -5,7 +5,7 @@ import importlib
 from typing import Dict, List
 
 from repro.configs.base import (
-    ArchConfig, MLAConfig, MoEConfig, SSMConfig, XLSTMConfig,
+    ArchConfig, MLAConfig, MoEConfig, SSMConfig, XLSTMConfig, YarnConfig,
     INPUT_SHAPES, ShapeSpec, param_count,
 )
 
@@ -20,6 +20,7 @@ _MODULES = {
     "qwen2-vl-7b":            "repro.configs.qwen2_vl_7b",
     "llama4-scout-17b-a16e":  "repro.configs.llama4_scout_17b_a16e",
     "starcoder2-3b":          "repro.configs.starcoder2_3b",
+    "mellum2-12b-a2.5b":      "repro.configs.mellum2_12b_a2_5b",
 }
 
 # (arch, shape) combos intentionally skipped, with reasons (DESIGN.md §4).
@@ -58,6 +59,7 @@ def combos(include_skips: bool = False):
 
 __all__ = [
     "ArchConfig", "MLAConfig", "MoEConfig", "SSMConfig", "XLSTMConfig",
+    "YarnConfig",
     "INPUT_SHAPES", "ShapeSpec", "param_count", "SKIPS",
     "list_archs", "get_config", "get_smoke", "combos",
 ]

@@ -20,9 +20,25 @@ class MoEConfig:
     n_shared: int = 0           # shared (always-on) experts
     d_expert: int = 0           # per-expert ffn width (0 -> use d_ff)
     router: str = "softmax"     # softmax | sigmoid (deepseek-v3 uses sigmoid)
-    capacity_factor: float = 1.25
+    # softmax router: renormalize the top-k weights to sum to 1 (the
+    # published norm_topk_prob); the sigmoid router always does
+    norm_topk: bool = False
     aux_loss_coef: float = 0.001
     first_k_dense: int = 0      # leading dense layers (deepseek-v3: 3)
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (Peng et al., arXiv:2309.00071), as published
+    in a config's ``rope_parameters``: frequencies interpolated by
+    ``factor`` below the ``beta_slow`` rotations-per-context band,
+    extrapolated above ``beta_fast``, a linear ramp between, and cos and
+    sin scaled by ``attention_factor``."""
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -71,10 +87,17 @@ class ArchConfig:
     attn_kind: str = "gqa"      # gqa | mla | none
     rope_kind: str = "rope"     # rope | mrope | none
     rope_theta: float = 10000.0
+    # YaRN tables on the full-attention layers (window_pattern's global
+    # layers; every layer where there is no window); plain RoPE elsewhere
+    yarn: Optional[YarnConfig] = None
     norm: str = "rmsnorm"       # rmsnorm | layernorm
     bias: bool = False
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
+    # the first experts_held of moe.n_experts are held here (0: all of
+    # them): one chip's share under expert parallelism; the router keeps
+    # its full width
+    experts_held: int = 0
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     xlstm: Optional[XLSTMConfig] = None
@@ -104,19 +127,28 @@ class ArchConfig:
     scan_group: int = 1
     # per-layer activation rematerialization (jax.checkpoint around bodies)
     remat_layers: bool = False
-    # MoE dispatch mode: "gathered" (experts fsdp-gathered, baseline) or
-    # "ep" (expert-parallel with explicit sharding constraints, optimized)
+    # MoE lowering: "gathered" (the held experts' product where the
+    # activations are) or "ep_shmap" (the experts split over the mesh's
+    # 'model' axis, each shard computing its own, one psum)
     moe_mode: str = "gathered"
 
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
 
+    @property
+    def held_experts(self) -> int:
+        """How many experts this program holds (from expert 0)."""
+        n = self.experts_held or self.moe.n_experts
+        assert 0 < n <= self.moe.n_experts, self.name
+        return n
+
     def validate(self) -> "ArchConfig":
         assert self.n_heads % max(self.n_kv_heads, 1) == 0, self.name
         assert self.family in ("dense", "moe", "hybrid", "ssm", "audio", "vlm")
         if self.family == "moe":
             assert self.moe is not None
+            self.held_experts
         return self
 
     def replace(self, **kw) -> "ArchConfig":
@@ -173,7 +205,7 @@ def param_count(cfg: ArchConfig) -> Tuple[int, int]:
             a = 0  # mamba layers; shared block added below
         if cfg.moe is not None and i >= cfg.moe.first_k_dense:
             de = cfg.moe.d_expert or cfg.d_ff
-            routed = cfg.moe.n_experts * ffn_dense(de)
+            routed = cfg.held_experts * ffn_dense(de)
             shared = cfg.moe.n_shared * ffn_dense(de)
             router = D * cfg.moe.n_experts
             total += a + routed + shared + router

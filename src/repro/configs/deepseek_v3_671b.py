@@ -33,8 +33,6 @@ def smoke() -> ArchConfig:
         d_ff=512, vocab=512, max_seq=256,
         mla=MLAConfig(q_lora_rank=128, kv_lora_rank=64,
                       qk_nope_dim=64, qk_rope_dim=32, v_head_dim=64),
-        # capacity_factor >= n_experts => lossless routing, so smoke tests
-        # can assert exact prefill/decode equivalence
         moe=MoEConfig(n_experts=4, top_k=2, n_shared=1, d_expert=128,
-                      router="sigmoid", first_k_dense=1, capacity_factor=4.0),
+                      router="sigmoid", first_k_dense=1),
     ).validate()

@@ -34,5 +34,5 @@ def smoke() -> ArchConfig:
         n_layers=2, d_model=256, n_heads=8, n_kv_heads=2, head_dim=32,
         d_ff=512, vocab=512, max_seq=256, window=64, window_pattern=2,
         moe=MoEConfig(n_experts=4, top_k=1, n_shared=1, d_expert=512,
-                      router="sigmoid", capacity_factor=4.0),
+                      router="sigmoid"),
     ).validate()

@@ -576,6 +576,14 @@ class TrainerExecutor(Executor):
                     self.state, metrics = self._jitted(self.state, batch)
             with obs_trace.span("readback-wait", "trainer"):
                 metrics = {k: float(v) for k, v in metrics.items()}
+            if "moe_pairs_held" in metrics:
+                from repro.rl.engine import charge_expert_tally, \
+                    publish_expert_gauges
+                charge_expert_tally(
+                    metrics["moe_pairs_held"], metrics["moe_experts_hit"],
+                    self.cfg.n_layers - self.cfg.moe.first_k_dense,
+                    trainer=True)
+                publish_expert_gauges(self.cfg)
             metrics["mean_reward"] = scored.get("mean_reward", 0.0)
             self.metrics_history.append(metrics)
             with obs_trace.span("set-output", "trainer"):

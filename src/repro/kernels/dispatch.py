@@ -2,8 +2,9 @@
 
 Replaces the ad-hoc ``INTERPRET`` flag that used to live in ``ops.py``.
 Every caller (trainer loss, reference scoring, decode sampling, dense-causal
-attention) goes through the public entry points here --
-``token_logprob`` / ``sample`` / ``attention`` / ``int8_matmul`` -- and the
+attention, the expert layer's products) goes through the public entry
+points here -- ``token_logprob`` / ``sample`` / ``attention`` /
+``paged_attention`` / ``grouped_matmul`` / ``int8_matmul`` -- and the
 routing policy picks one of three backends per call site from platform,
 env, dtype and static shapes (``auto``: compiled kernels above the size
 thresholds on a TPU, streamed jnp everywhere else):
@@ -74,11 +75,11 @@ def kernel_mode() -> str:
     return "auto"
 
 
-def _route(path: str, n: int, dtype, threshold_var: str,
-           default_min: int) -> str:
+def _route(path: str, n: int, dtype, threshold_var, default_min: int) -> str:
     """Pick a backend for hot path ``path`` whose dominant streamed axis
-    has size n, and count the choice (at trace time: once per compiled
-    call site, not per call) for ``routes_taken``."""
+    has size n (no size threshold where ``threshold_var`` is None), and
+    count the choice (at trace time: once per compiled call site, not per
+    call) for ``routes_taken``."""
     backend = _pick(n, dtype, threshold_var, default_min)
     with _ROUTES_LOCK:
         _ROUTES[(path, backend)] += 1
@@ -95,7 +96,8 @@ def _pick(n: int, dtype, threshold_var: str, default_min: int) -> str:
     # streamed-jnp path both lowers and beats the Pallas interpreter.
     # Below the threshold kernel launch overhead dominates, so jnp.
     wants = mode == "compile" or jax.default_backend() == "tpu"
-    if wants and n >= _env_int(threshold_var, default_min):
+    if wants and (threshold_var is None
+                  or n >= _env_int(threshold_var, default_min)):
         return "pallas_compile"
     return "jnp"
 
@@ -120,6 +122,17 @@ def routes_taken() -> dict:
         for (path, backend), n in _ROUTES.items():
             out.setdefault(path, {})[backend] = n
     return out
+
+
+#: the backends in the order ``route_code`` numbers them
+BACKENDS = ("jnp", "pallas_interpret", "pallas_compile")
+
+
+def route_code(path: str):
+    """The backend hot path ``path`` was staged on, as its index in
+    ``BACKENDS`` (the highest if several), or None if never staged."""
+    taken = routes_taken().get(path)
+    return max(BACKENDS.index(b) for b in taken) if taken else None
 
 
 def paged_block_pages() -> dict:
@@ -400,6 +413,63 @@ def paged_attention(q, arena_k, arena_v, page_table, pos, *, window: int = 0):
         paged_attention_kernel, window=window,
         interpret=backend != "pallas_compile"))(
             q, arena_k, arena_v, page_table, pos)
+
+
+# ------------------------------------------------------- grouped matmul ---
+
+def _gmm_kernel_call(lhs, rhs, sizes, tm, compiled, transpose_rhs=False):
+    from repro.kernels.grouped_matmul import gmm, tile_groups
+    tg, nu = tile_groups(sizes, tm, lhs.shape[0] // tm)
+    return _per_device(functools.partial(
+        gmm, tm=tm, transpose_rhs=transpose_rhs,
+        interpret=not compiled))(lhs, rhs, tg, nu)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gmm_vjp(lhs, rhs, sizes, tm: int, compiled: bool):
+    return _gmm_kernel_call(lhs, rhs, sizes, tm, compiled)
+
+
+def _gmm_vjp_fwd(lhs, rhs, sizes, tm, compiled):
+    return _gmm_kernel_call(lhs, rhs, sizes, tm, compiled), (lhs, rhs, sizes)
+
+
+def _gmm_vjp_bwd(tm, compiled, res, g):
+    from repro.kernels.grouped_matmul import tgmm, tile_groups
+    lhs, rhs, sizes = res
+    d_lhs = _gmm_kernel_call(g, rhs, sizes, tm, compiled,
+                             transpose_rhs=True)
+    tg, nu = tile_groups(sizes, tm, lhs.shape[0] // tm)
+    d_rhs = _per_device(functools.partial(
+        tgmm, tm=tm, interpret=not compiled))(lhs, g, sizes, tg, nu)
+    return d_lhs, d_rhs.astype(rhs.dtype), None
+
+
+_gmm_vjp.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)
+
+
+def grouped_matmul(lhs, rhs, sizes, *, tm: int):
+    """The expert product over tile-aligned groups
+    (``kernels/grouped_matmul.py``'s layout): lhs [M, K] holds group g's
+    ``sizes[g]`` rows from a multiple of ``tm``; rhs [G, K, N] ->
+    [M, N], differentiable in lhs and rhs.
+
+    The kernel route (TPU) runs the Pallas ``gmm`` forward and ``gmm``
+    against the transposed weights plus ``tgmm`` backward, over the
+    tiles in use only; rows past them are left unwritten.  The jnp route
+    is ``jax.lax.ragged_dot`` (which the CPU computes densely).  On the
+    kernel route a weight with no column band that fits the kernel's
+    VMEM budget, either way round, is an error."""
+    from repro.kernels.grouped_matmul import col_tile, gmm_ref
+    backend = _route("grouped_matmul", lhs.shape[0], lhs.dtype, None, 0)
+    if backend == "jnp":
+        return gmm_ref(lhs, rhs, sizes, tm=tm)
+    K, N = rhs.shape[1:]
+    if not (col_tile(K, N, rhs.dtype.itemsize)
+            and col_tile(N, K, rhs.dtype.itemsize)):
+        raise ValueError(f"grouped_matmul: no column band of a {K}x{N} "
+                         f"{rhs.dtype} weight fits the kernel's VMEM budget")
+    return _gmm_vjp(lhs, rhs, sizes, tm, backend == "pallas_compile")
 
 
 # --------------------------------------------------------------- matmul ---

@@ -265,7 +265,7 @@ def main():
     ap.add_argument("--no-remat", action="store_true")
     ap.add_argument("--variant", default="")
     ap.add_argument("--moe-mode", default="gathered",
-                    choices=["gathered", "ep", "ep_shmap"])
+                    choices=["gathered", "ep_shmap"])
     ap.add_argument("--prefill-out-shardings", action="store_true")
     ap.add_argument("--accum-steps", type=int, default=1)
     ap.add_argument("--seq-parallel", action="store_true")

@@ -130,16 +130,18 @@ def _qkv(p, x, cfg):
 
 
 def gqa_forward(p, x, cfg, *, window: int = 0, positions=None,
-                mrope_pos=None, causal: bool = True, q_offset: int = 0):
+                mrope_pos=None, causal: bool = True, q_offset: int = 0,
+                yarn=None):
     """Full-sequence (train/prefill) GQA.  Returns (y, (k, v)) so callers can
-    build KV caches from prefill."""
+    build KV caches from prefill.  ``yarn``: the layer's YaRN table
+    (``backbone.layer_yarn``), None for plain RoPE."""
     B, S, _ = x.shape
     q, k, v = _qkv(p, x, cfg)
     if cfg.rope_kind == "rope":
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(S) + q_offset, (B, S))
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, yarn)
+        k = apply_rope(k, positions, cfg.rope_theta, yarn)
     elif cfg.rope_kind == "mrope":
         q = apply_mrope(q, mrope_pos, cfg.rope_theta)
         k = apply_mrope(k, mrope_pos, cfg.rope_theta)
@@ -159,7 +161,7 @@ def gqa_cross_forward(p, x, k, v, cfg):
 
 
 def gqa_decode(p, x, cache_k, cache_v, cache_pos, pos, cfg, *,
-               window: int = 0, mrope_pos=None):
+               window: int = 0, mrope_pos=None, yarn=None):
     """One-token decode.  x: [B, 1, D]; cache_[kv]: [B, Sc, K, hd];
     cache_pos: [Sc] absolute position per slot (-1 = empty); pos: scalar
     or [B] (one decode cursor per row).
@@ -182,8 +184,8 @@ def gqa_decode(p, x, cache_k, cache_v, cache_pos, pos, cfg, *,
     posb = pos[:, None] if per_row \
         else jnp.broadcast_to(pos[None, None], (B, 1))
     if cfg.rope_kind == "rope":
-        q = apply_rope(q, posb, cfg.rope_theta)
-        k = apply_rope(k, posb, cfg.rope_theta)
+        q = apply_rope(q, posb, cfg.rope_theta, yarn)
+        k = apply_rope(k, posb, cfg.rope_theta, yarn)
     elif cfg.rope_kind == "mrope":
         mp = mrope_pos if mrope_pos is not None else jnp.stack([posb] * 3)
         q = apply_mrope(q, mp, cfg.rope_theta)
@@ -228,7 +230,7 @@ def gqa_decode(p, x, cache_k, cache_v, cache_pos, pos, cfg, *,
 
 
 def gqa_decode_paged(p, x, arena_k, arena_v, page_table, pos, cfg, *,
-                     window: int = 0):
+                     window: int = 0, yarn=None):
     """One-token decode against a paged KV arena (``models/paging.py``).
 
     x: [B, 1, D]; arena_[kv]: [n_pages + 1, P, K * hd] (last page is the
@@ -248,8 +250,8 @@ def gqa_decode_paged(p, x, arena_k, arena_v, page_table, pos, cfg, *,
     q, k, v = _qkv(p, x, cfg)
     posb = pos[:, None]
     if cfg.rope_kind == "rope":
-        q = apply_rope(q, posb, cfg.rope_theta)
-        k = apply_rope(k, posb, cfg.rope_theta)
+        q = apply_rope(q, posb, cfg.rope_theta, yarn)
+        k = apply_rope(k, posb, cfg.rope_theta, yarn)
     P = arena_k.shape[1]
     W = page_table.shape[1]
     rows = jnp.arange(B)
@@ -270,7 +272,7 @@ def gqa_decode_paged(p, x, arena_k, arena_v, page_table, pos, cfg, *,
 
 
 def gqa_extend(p, x, prefix_k, prefix_v, cfg, *, q_offset: int,
-               window: int = 0):
+               window: int = 0, yarn=None):
     """Prefill continuation over a cached prefix (radix-hit admission).
 
     x: [B, S, D] embeds of the *suffix* tokens (absolute positions
@@ -286,8 +288,8 @@ def gqa_extend(p, x, prefix_k, prefix_v, cfg, *, q_offset: int,
     q, k, v = _qkv(p, x, cfg)
     if cfg.rope_kind == "rope":
         positions = jnp.broadcast_to(jnp.arange(S) + q_offset, (B, S))
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, yarn)
+        k = apply_rope(k, positions, cfg.rope_theta, yarn)
     q, k, v = constrain_attn(q, k, v)
     cat_k = jnp.concatenate([prefix_k.astype(k.dtype), k], axis=1)
     cat_v = jnp.concatenate([prefix_v.astype(v.dtype), v], axis=1)

@@ -133,23 +133,34 @@ def _is_global_layer(cfg, i):
     return False
 
 
+def layer_yarn(cfg, i):
+    """Layer ``i``'s YaRN table: the config's on its full-attention
+    layers, None (plain RoPE) elsewhere."""
+    return cfg.yarn if cfg.yarn is not None and _is_global_layer(cfg, i) \
+        else None
+
+
 def _layer_windows(cfg, n_layers, offset=0):
     return jnp.array(
         [0 if _is_global_layer(cfg, offset + i) else cfg.window
          for i in range(n_layers)], dtype=jnp.int32)
 
 
-def _attn_block(p, x, cfg, *, window, mrope_pos=None, q_offset=0):
+def _attn_block(p, x, cfg, *, window, mrope_pos=None, q_offset=0,
+                yarn=None):
     h = norm(x, p["ln1"], cfg.norm)
     if cfg.attn_kind == "mla":
         y, kv = attn.mla_forward(p["attn"], h, cfg, q_offset=q_offset)
     else:
         y, kv = attn.gqa_forward(p["attn"], h, cfg, window=window,
-                                 mrope_pos=mrope_pos, q_offset=q_offset)
+                                 mrope_pos=mrope_pos, q_offset=q_offset,
+                                 yarn=yarn)
     return x + y, kv
 
 
 def _ffn_block(p, x, cfg):
+    """(x + the layer's FFN, its tally): an expert layer's
+    ``ffn.moe_forward`` tally, 0.0 for a dense one."""
     h = norm(x, p["ln2"], cfg.norm)
     if "moe" in p:
         y, aux = ffnmod.moe_forward(p["moe"], h, cfg)
@@ -159,11 +170,36 @@ def _ffn_block(p, x, cfg):
     return x + y, aux
 
 
+def zero_tally(stacked):
+    """What a layer stack's tallies sum from: a vector for expert layers
+    (``ffn.TALLY``), the scalar 0.0 for dense ones."""
+    return jnp.zeros((len(ffnmod.TALLY),)) if "moe" in stacked else 0.0
+
+
+def scan_tallied(body, x, xs, cfg, stacked):
+    """``_scan`` a layer stack with ``body(h, inputs) -> (h, tally, ys)``:
+    returns (x, summed tally, ys).  A dense stack's tallies (0.0) are
+    dropped inside the body, so its program is the plain scan over h."""
+    if "moe" not in stacked:
+        def plain(h, inputs):
+            h, _, ys = body(h, inputs)
+            return h, ys
+        x, ys = _scan(plain, x, xs, cfg)
+        return x, 0.0, ys
+
+    def tallied(carry, inputs):
+        h, t = carry
+        h, a, ys = body(h, inputs)
+        return (h, t + a), ys
+    (x, t), ys = _scan(tallied, (x, zero_tally(stacked)), xs, cfg)
+    return x, t, ys
+
+
 def _decoder_layer(p, x, cfg, *, window, mrope_pos=None, q_offset=0,
-                   collect_kv=False):
+                   collect_kv=False, yarn=None):
     x = constrain_batch(x)
     x, kv = _attn_block(p, x, cfg, window=window, mrope_pos=mrope_pos,
-                        q_offset=q_offset)
+                        q_offset=q_offset, yarn=yarn)
     x, aux = _ffn_block(p, x, cfg)
     return x, aux, (kv if collect_kv else None)
 
@@ -265,37 +301,41 @@ def real_layers(cfg, kind: str = "train", seq_len: int = 0) -> int:
 
 
 def _scan_decoder_uniform(stacked, x, cfg, window, mrope_pos=None,
-                          collect_kv=False):
-    """Scan a segment where every layer shares the same (static) window."""
+                          collect_kv=False, yarn=None):
+    """Scan a segment where every layer shares the same (static) window
+    and rotary table."""
     def body(carry, lp):
         h, aux = carry
         h, a, kv = _decoder_layer(lp, h, cfg, window=window,
-                                  mrope_pos=mrope_pos, collect_kv=collect_kv)
+                                  mrope_pos=mrope_pos, collect_kv=collect_kv,
+                                  yarn=yarn)
         return (h, aux + a), kv
 
-    (x, aux), kvs = _scan(body, (x, 0.0), stacked, cfg)
+    (x, aux), kvs = _scan(body, (x, zero_tally(stacked)), stacked, cfg)
     return x, aux, kvs
 
 
 def _segment_windows(cfg, n_layers, offset=0, seq_len=0):
-    """Split [offset, offset+n) into maximal runs of equal window size.
+    """Split [offset, offset+n) into maximal runs of equal window size
+    and rotary table (``layer_yarn``).
 
     When seq_len is given and window >= seq_len, windowed attention equals
     full attention exactly, so segments merge (one scan instead of 2L/pattern
-    scans -- vital for llama4's 3:1 iRoPE pattern at train_4k)."""
-    def win(i):
+    scans -- vital for llama4's 3:1 iRoPE pattern at train_4k); a full
+    layer with YaRN tables never merges with a plain-RoPE one."""
+    def kind(i):
         w = 0 if _is_global_layer(cfg, offset + i) else cfg.window
         if w and seq_len and w >= seq_len:
             w = 0
-        return w
+        return w, layer_yarn(cfg, offset + i) is not None
     runs = []
     i = 0
     while i < n_layers:
-        w = win(i)
+        k = kind(i)
         j = i
-        while j < n_layers and win(j) == w:
+        while j < n_layers and kind(j) == k:
             j += 1
-        runs.append((i, j, w))
+        runs.append((i, j, k[0]))
         i = j
     return runs
 
@@ -311,7 +351,8 @@ def _run_decoder_stack(stacked, x, cfg, n_layers, offset=0, mrope_pos=None,
     for (i, j, w) in _segment_windows(cfg, n_layers, offset, seq_len):
         seg = jax.tree.map(lambda a: a[i:j], stacked)
         x, a, kvs = _scan_decoder_uniform(seg, x, cfg, w, mrope_pos=mrope_pos,
-                                          collect_kv=collect_kv)
+                                          collect_kv=collect_kv,
+                                          yarn=layer_yarn(cfg, offset + i))
         aux = aux + a
         if collect_kv:
             kvs_all.append(kvs)
@@ -366,7 +407,8 @@ def forward_train(params: Params, cfg: ArchConfig, batch) -> tuple:
         x, a, _ = _run_decoder_stack(params["moe_layers"], x, cfg,
                                      cfg.n_layers - fkd, offset=fkd,
                                      seq_len=x.shape[1])
-        aux["moe_aux"] += a
+        tally = ffnmod.tally_dict(a)
+        aux = {**tally, "moe_aux": aux["moe_aux"] + tally["moe_aux"]}
         if cfg.mtp and "mtp" in params:
             # Multi-token prediction: predict t+2 from (h_t, emb(y_{t+1}))
             h = norm(x, params["mtp"]["norm"], cfg.norm)

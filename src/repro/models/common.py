@@ -39,13 +39,39 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: [..., S, H, hd]; positions: broadcastable to [..., S]."""
+def yarn_freqs(head_dim: int, theta: float, yarn):
+    """YaRN's inverse frequencies (arXiv:2309.00071, as Hugging Face's
+    ``_compute_yarn_parameters`` with ``truncate``): pair ``i`` keeps
+    its frequency below the correction range, takes it over ``factor``
+    above, and a linear ramp between; the range runs from the dim at
+    which ``beta_fast`` rotations fit the original context (floored) to
+    the one for ``beta_slow`` (ceiled)."""
+    def dim_for(rotations):
+        return head_dim * np.log(yarn.original_max
+                                 / (rotations * 2 * np.pi)) \
+            / (2 * np.log(theta))
+    low = max(np.floor(dim_for(yarn.beta_fast)), 0)
+    high = min(np.ceil(dim_for(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2) - low) / (high - low), 0, 1)
+    base = rope_freqs(head_dim, theta)
+    return base / yarn.factor * ramp + base * (1 - ramp)
+
+
+def apply_rope(x, positions, theta: float, yarn=None):
+    """x: [..., S, H, hd]; positions: broadcastable to [..., S].  With
+    ``yarn`` (a ``YarnConfig``), YaRN's frequencies, and cos and sin
+    scaled by its attention factor."""
     hd = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(hd, theta), dtype=jnp.float32)
+    table = rope_freqs(hd, theta) if yarn is None \
+        else yarn_freqs(hd, theta, yarn)
+    freqs = jnp.asarray(table, dtype=jnp.float32)
     ang = positions[..., :, None].astype(jnp.float32) * freqs  # [..., S, hd/2]
     cos = jnp.cos(ang)[..., :, None, :]                        # [..., S, 1, hd/2]
     sin = jnp.sin(ang)[..., :, None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

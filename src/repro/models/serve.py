@@ -77,10 +77,23 @@ def segment_layout(cfg: ArchConfig):
     return [(j - i, w) for (i, j, w) in attn_segments(cfg, cfg.n_layers)]
 
 
+def _moe_tally(cfg, cache, tally):
+    """``cache["moe"]`` (pairs held, held experts hit, expert-product
+    calls; uint32, wrapping) plus one pass's layer ``tally``."""
+    calls = cfg.n_layers - cfg.moe.first_k_dense
+    add = jnp.stack([tally[1], tally[2], jnp.float32(calls)])
+    return cache["moe"] + add.astype(jnp.uint32)
+
+
 def init_cache(cfg: ArchConfig, B: int, cache_len: int,
                dtype=jnp.bfloat16, *, layout: str = "dense",
                page_size: int = 0, n_pages: int = 0) -> Cache:
+    """An empty cache.  An expert model's cache also carries ``"moe"``:
+    what its expert layers computed since (``_moe_tally``), which the
+    engine reads back with its harvest."""
     cache: Cache = {"pos": jnp.zeros((), jnp.int32)}
+    if cfg.family == "moe":
+        cache["moe"] = jnp.zeros((3,), jnp.uint32)
     mk_seg = _mla_seg if cfg.attn_kind == "mla" else _kv_seg
 
     if layout == "paged":
@@ -156,7 +169,8 @@ def _write_seg(seg, kvs):
 
 
 def _prefill_collect(params, cfg, x, mrope_pos=None):
-    """Run decoder stacks collecting per-segment stacked KVs."""
+    """Run decoder stacks collecting per-segment stacked KVs; returns
+    (x, kvs, the layers' summed tally)."""
     if cfg.family == "moe":
         stacks = []
         fkd = cfg.moe.first_k_dense
@@ -166,12 +180,14 @@ def _prefill_collect(params, cfg, x, mrope_pos=None):
     else:
         stacks = [(params["layers"], cfg.n_layers, 0)]
     kv_segs = []
+    tally = 0.0
     for stacked, n, off in stacks:
-        x, _, kvs = bb._run_decoder_stack(stacked, x, cfg, n, offset=off,
+        x, a, kvs = bb._run_decoder_stack(stacked, x, cfg, n, offset=off,
                                           mrope_pos=mrope_pos,
                                           collect_kv=True)
         kv_segs.extend(kvs)
-    return x, kv_segs
+        tally = tally + a
+    return x, kv_segs, tally
 
 
 def _extend_collect(params, cfg, x, prefix_kvs, q_offset: int):
@@ -183,7 +199,8 @@ def _extend_collect(params, cfg, x, prefix_kvs, q_offset: int):
     [L_seg, B, q_offset, K, hd] gathered from the radix-shared pages.
     Per-query-row attention is independent of the other rows, so the
     result is bit-for-bit what ``_prefill_collect`` computes for the
-    same positions of the full prompt."""
+    same positions of the full prompt.  Also returns the layers' summed
+    tally."""
     if cfg.family == "moe":
         stacks = []
         fkd = cfg.moe.first_k_dense
@@ -193,26 +210,29 @@ def _extend_collect(params, cfg, x, prefix_kvs, q_offset: int):
     else:
         stacks = [(params["layers"], cfg.n_layers, 0)]
     kv_segs = []
+    tally = 0.0
     si = 0
     for stacked, n, off in stacks:
         for (i, j, w) in attn_segments(cfg, n, off):
             seg = jax.tree.map(lambda a: a[i:j], stacked)
             pk, pv = prefix_kvs[si]
 
-            def body(h, inputs, w=w):
+            def body(h, inputs, w=w, yarn=bb.layer_yarn(cfg, off + i)):
                 lp, pk_l, pv_l = inputs
                 h = constrain_batch(h)
                 hh = norm(h, lp["ln1"], cfg.norm)
                 y, kv = attn.gqa_extend(lp["attn"], hh, pk_l, pv_l, cfg,
-                                        q_offset=q_offset, window=w)
+                                        q_offset=q_offset, window=w,
+                                        yarn=yarn)
                 h = h + y
-                h, _ = bb._ffn_block(lp, h, cfg)
-                return h, kv
+                h, a = bb._ffn_block(lp, h, cfg)
+                return h, a, kv
 
-            x, kvs = bb._scan(body, x, (seg, pk, pv), cfg)
+            x, t, kvs = bb.scan_tallied(body, x, (seg, pk, pv), cfg, seg)
             kv_segs.append(kvs)
+            tally = tally + t
             si += 1
-    return x, kv_segs
+    return x, kv_segs, tally
 
 
 def prefill(params, cfg: ArchConfig, batch, cache_len: int,
@@ -241,10 +261,13 @@ def prefill(params, cfg: ArchConfig, batch, cache_len: int,
         mrope_pos = jnp.concatenate([vis, txt], axis=-1)
 
     if cfg.family in ("dense", "vlm", "moe"):
-        x, kv_segs = _prefill_collect(params, cfg, x, mrope_pos=mrope_pos)
+        x, kv_segs, tally = _prefill_collect(params, cfg, x,
+                                             mrope_pos=mrope_pos)
         cache["segments"] = [
             _write_seg(seg, kvs)
             for seg, kvs in zip(cache["segments"], kv_segs)]
+        if "moe" in cache:
+            cache["moe"] = _moe_tally(cfg, cache, tally)
         cache["pos"] = jnp.asarray(S + prefix, jnp.int32)
         return bb._logits(params, cfg, x[:, -1]), cache
 
@@ -322,8 +345,10 @@ def prefill(params, cfg: ArchConfig, batch, cache_len: int,
 
 # ------------------------------------------------------------ decode_step -
 
-def _decode_seg(stacked_params, seg, x, pos, cfg, window, mrope_pos=None):
-    """Scan one attention segment during decode."""
+def _decode_seg(stacked_params, seg, x, pos, cfg, window, mrope_pos=None,
+                yarn=None):
+    """Scan one attention segment during decode; returns (x, new segment,
+    the layers' summed tally)."""
     if "ckv" in seg:
         def body(h, inputs):
             lp, ckv, krope = inputs
@@ -332,13 +357,14 @@ def _decode_seg(stacked_params, seg, x, pos, cfg, window, mrope_pos=None):
             y, ckv, krope, sp = attn.mla_decode(
                 lp["attn"], hh, ckv, krope, seg["slot_pos"], pos, cfg)
             h = h + y
-            h, _ = bb._ffn_block(lp, h, cfg)
-            return h, (ckv, krope, sp)
+            h, a = bb._ffn_block(lp, h, cfg)
+            return h, a, (ckv, krope, sp)
 
-        x, (ckv, krope, sps) = bb._scan(
-            body, x, (stacked_params, seg["ckv"], seg["krope"]), cfg)
+        x, tally, (ckv, krope, sps) = bb.scan_tallied(
+            body, x, (stacked_params, seg["ckv"], seg["krope"]), cfg,
+            stacked_params)
         new_seg = {"ckv": ckv, "krope": krope, "slot_pos": sps[0]}
-        return x, new_seg
+        return x, new_seg, tally
 
     def body(h, inputs):
         lp, ck, cv = inputs
@@ -346,35 +372,38 @@ def _decode_seg(stacked_params, seg, x, pos, cfg, window, mrope_pos=None):
         hh = norm(h, lp["ln1"], cfg.norm)
         y, ck, cv, sp = attn.gqa_decode(lp["attn"], hh, ck, cv,
                                         seg["slot_pos"], pos, cfg,
-                                        window=window, mrope_pos=mrope_pos)
+                                        window=window, mrope_pos=mrope_pos,
+                                        yarn=yarn)
         h = h + y
-        h, _ = bb._ffn_block(lp, h, cfg)
-        return h, (ck, cv, sp)
+        h, a = bb._ffn_block(lp, h, cfg)
+        return h, a, (ck, cv, sp)
 
-    x, (ck, cv, sps) = bb._scan(body, x, (stacked_params, seg["k"],
-                                seg["v"]), cfg)
+    x, tally, (ck, cv, sps) = bb.scan_tallied(
+        body, x, (stacked_params, seg["k"], seg["v"]), cfg, stacked_params)
     new_seg = {"k": ck, "v": cv, "slot_pos": sps[0]}
-    return x, new_seg
+    return x, new_seg, tally
 
 
-def _decode_seg_paged(stacked_params, seg, x, page_table, pos, cfg, window):
+def _decode_seg_paged(stacked_params, seg, x, page_table, pos, cfg, window,
+                      yarn=None):
     """Scan one attention segment during paged decode: every layer
     scatters its new KV into the row's mapped page and attends through
-    the page table (``dispatch.paged_attention``)."""
+    the page table (``dispatch.paged_attention``).  Returns (x, new
+    segment, the layers' summed tally)."""
     def body(h, inputs):
         lp, ak, av = inputs
         h = constrain_batch(h)
         hh = norm(h, lp["ln1"], cfg.norm)
         y, ak, av = attn.gqa_decode_paged(lp["attn"], hh, ak, av,
                                           page_table, pos, cfg,
-                                          window=window)
+                                          window=window, yarn=yarn)
         h = h + y
-        h, _ = bb._ffn_block(lp, h, cfg)
-        return h, (ak, av)
+        h, a = bb._ffn_block(lp, h, cfg)
+        return h, a, (ak, av)
 
-    x, (ak, av) = bb._scan(body, x, (stacked_params, seg["k"], seg["v"]),
-                           cfg)
-    return x, {"k": ak, "v": av}
+    x, tally, (ak, av) = bb.scan_tallied(
+        body, x, (stacked_params, seg["k"], seg["v"]), cfg, stacked_params)
+    return x, {"k": ak, "v": av}, tally
 
 
 def decode_step(params, cfg: ArchConfig, cache: Cache, tokens):
@@ -400,23 +429,28 @@ def decode_step(params, cfg: ArchConfig, cache: Cache, tokens):
             stacks = [(params["layers"], cfg.n_layers, 0)]
         paged = "page_table" in cache
         new_segs = []
+        tally = 0.0
         si = 0
         for stacked, n, off in stacks:
             for (i, j, w) in attn_segments(cfg, n, off):
                 lp = jax.tree.map(lambda a: a[i:j], stacked)
+                yarn = bb.layer_yarn(cfg, off + i)
                 if paged:
-                    x, new_seg = _decode_seg_paged(
+                    x, new_seg, t = _decode_seg_paged(
                         lp, cache["segments"][si], x, cache["page_table"],
-                        pos, cfg, w)
+                        pos, cfg, w, yarn=yarn)
                 else:
-                    x, new_seg = _decode_seg(lp, cache["segments"][si], x,
-                                             pos, cfg, w,
-                                             mrope_pos=mrope_pos)
+                    x, new_seg, t = _decode_seg(
+                        lp, cache["segments"][si], x, pos, cfg, w,
+                        mrope_pos=mrope_pos, yarn=yarn)
                 new_segs.append(new_seg)
+                tally = tally + t
                 si += 1
         new_cache = {"pos": pos + 1, "segments": new_segs}
         if paged:
             new_cache["page_table"] = cache["page_table"]
+        if "moe" in cache:
+            new_cache["moe"] = _moe_tally(cfg, cache, tally)
         return bb._logits(params, cfg, x[:, -1]), new_cache
 
     if cfg.family == "hybrid":
@@ -596,5 +630,8 @@ def stitch_cache_row(cache: Cache, row_cache: Cache, slot) -> Cache:
                 seg[name], rseg[name].astype(seg[name].dtype), slot, axis=1)
         out["slot_pos"] = jnp.maximum(seg["slot_pos"], rseg["slot_pos"])
         new_segs.append(out)
-    return {"pos": cache["pos"].at[slot].set(row_cache["pos"]),
-            "segments": new_segs}
+    new_cache = {"pos": cache["pos"].at[slot].set(row_cache["pos"]),
+                 "segments": new_segs}
+    if "moe" in cache:
+        new_cache["moe"] = cache["moe"] + row_cache["moe"]
+    return new_cache

@@ -114,20 +114,6 @@ def constrain_attn(q, k, v):
     return c(q), c(k), c(v)
 
 
-def constrain_experts(x):
-    """Anchor a MoE capacity buffer [B, E, C, D]: batch over dp AND experts
-    over 'model' (expert parallelism).  XLA realizes the transition from
-    token-sharded to expert-sharded as an all-to-all -- the EP dispatch
-    (moe_mode='ep') -- replacing the baseline's per-layer expert-weight
-    all-gather."""
-    mesh = _ACT_MESH["mesh"]
-    if mesh is None or not hasattr(x, "ndim") or x.ndim != 4:
-        return x
-    spec = (dp_axes(mesh), "model", None, None)
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, _fit(mesh, x.shape, spec)))
-
-
 def _path_str(path) -> str:
     return "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
                     for k in path)

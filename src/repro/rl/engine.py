@@ -57,6 +57,31 @@ from repro.rl.rollout import admit_row, admit_row_paged, release_row, \
 from repro.rl.scheduler import RowJob
 
 
+def charge_expert_tally(pairs, hits, products, *, trainer: bool = False):
+    """Add what expert products computed to the process's counters:
+    ``moe.pairs_held`` (token-expert pairs on held experts),
+    ``moe.experts_hit`` (held experts with a pair, summed per product
+    call) and ``moe.products`` (product calls: one per expert layer per
+    pass), on both sides of the loop; the trainer's part also under the
+    same names with ``.trainer`` appended."""
+    reg = obs_metrics.registry()
+    for name, v in (("moe.pairs_held", pairs), ("moe.experts_hit", hits),
+                    ("moe.products", products)):
+        reg.counter(name).inc(float(v))
+        if trainer:
+            reg.counter(name + ".trainer").inc(float(v))
+
+
+def publish_expert_gauges(cfg):
+    """Once an expert layer is staged: ``moe.experts_held`` and
+    ``kernels.grouped_matmul.route`` (``dispatch.BACKENDS`` index)."""
+    code = dispatch.route_code("grouped_matmul")
+    if code is not None:
+        reg = obs_metrics.registry()
+        reg.gauge("moe.experts_held").set(cfg.held_experts)
+        reg.gauge("kernels.grouped_matmul.route").set(code)
+
+
 class GroupLedger:
     """Accumulates a prompt's ``n_per_prompt`` sibling completions and
     computes RLOO/AIPO advantages when the GROUP completes, not when a
@@ -219,6 +244,9 @@ class RolloutEngine:
         self._rounds = reg.counter("engine.rounds")
         self._live_row_rounds = reg.counter("engine.live_row_rounds")
         reg.gauge("engine.slots").set(self.max_running_rows)
+        # expert models: the pool's running tally (``serve._moe_tally``)
+        # as last charged to the counters
+        self._moe_seen = np.zeros(3, np.uint32)
 
     # ----------------------------------------------------------- admission --
 
@@ -335,12 +363,15 @@ class RolloutEngine:
         ex = self.executor
         t0 = time.monotonic()
         with obs_trace.span("park", "engine"):
-            state = self.cache.get(self._rid) if self._rid is not None \
-                else start_row_pool(ex.cfg, self.max_running_rows,
-                                    self.total_len, self.prompt_len,
-                                    kv_layout=self.kv_layout,
-                                    kv_page_size=self.kv_page_size,
-                                    kv_pages=self.kv_pages)
+            if self._rid is not None:
+                state = self.cache.get(self._rid)
+            else:
+                state = start_row_pool(ex.cfg, self.max_running_rows,
+                                       self.total_len, self.prompt_len,
+                                       kv_layout=self.kv_layout,
+                                       kv_page_size=self.kv_page_size,
+                                       kv_pages=self.kv_pages)
+                self._moe_seen = np.zeros(3, np.uint32)
         self._rid = None
         with obs_trace.span("admit", "engine", waiting=len(self.waiting),
                             free=self.slots.free_count):
@@ -360,6 +391,8 @@ class RolloutEngine:
             for t in self.tickets.values():
                 t.chunks_done += 1
             state, emitted = self._harvest(state)
+        if "moe" in state.cache and self.tickets:
+            publish_expert_gauges(ex.cfg)
         if self.page_pool is not None:
             self._publish_block_pages()
             obs_trace.instant("pages", "engine",
@@ -401,6 +434,12 @@ class RolloutEngine:
             with obs_trace.span("readback-wait", "engine"):
                 tokens_np = np.asarray(state.tokens)
                 blp_np = np.asarray(state.behavior_logp)
+                moe_np = np.asarray(state.cache["moe"]) \
+                    if "moe" in state.cache else None
+            if moe_np is not None:
+                # uint32 differences wrap as the device's counts do
+                charge_expert_tally(*(moe_np - self._moe_seen))
+                self._moe_seen = moe_np
             for s in ready:
                 t = self.tickets.pop(s)
                 self.slots.release(s)

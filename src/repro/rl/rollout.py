@@ -254,7 +254,7 @@ def admit_row_paged(params, cfg, state: RolloutState, prompt, pages_row,
     prefill (empty prefix concat), so fresh admissions produce logits
     and KVs bit-for-bit equal to the dense ``start_rollout`` graft."""
     from repro.models import backbone as bb
-    from repro.models.serve import _extend_collect
+    from repro.models.serve import _extend_collect, _moe_tally
     sl = jnp.asarray(slot)
     Sp = prompt.shape[1]
     T = state.tokens.shape[1]
@@ -268,7 +268,7 @@ def admit_row_paged(params, cfg, state: RolloutState, prompt, pages_row,
         prefix_kvs.append((seg["k"][:, pages_row[:ncb]].reshape(shape),
                            seg["v"][:, pages_row[:ncb]].reshape(shape)))
     x = bb._embed(params, cfg, prompt[:, n_cached:])
-    x, kv_segs = _extend_collect(params, cfg, x, prefix_kvs, n_cached)
+    x, kv_segs, tally = _extend_collect(params, cfg, x, prefix_kvs, n_cached)
     last_logits = bb._logits(params, cfg, x[:, -1])
 
     pos_sfx = n_cached + jnp.arange(Sp - n_cached)
@@ -290,6 +290,8 @@ def admit_row_paged(params, cfg, state: RolloutState, prompt, pages_row,
             pages_row.astype(jnp.int32)),
         "segments": new_segs,
     }
+    if "moe" in cache:
+        new_cache["moe"] = _moe_tally(cfg, cache, tally)
     return RolloutState(
         tokens=state.tokens.at[sl].set(row_tokens),
         behavior_logp=state.behavior_logp.at[sl].set(0.0),

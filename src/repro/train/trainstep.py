@@ -69,6 +69,9 @@ def make_loss_fn(cfg, *, rho=4.0, clip_mode="aipo", kl_coef=0.0,
             loss = loss + mtp_weight * mtp_loss
             metrics = dict(metrics, mtp_loss=mtp_loss)
         metrics = dict(metrics, moe_aux=moe_aux, total_loss=loss)
+        for k in ("moe_pairs_held", "moe_experts_hit"):
+            if k in aux:
+                metrics[k] = aux[k]
         return loss, metrics
     return loss_fn
 

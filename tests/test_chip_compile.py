@@ -76,19 +76,29 @@ def test_fused_sample_compiles(spec, B, temperature):
              spec((B, VOCAB), jnp.float32), spec((2,), jnp.uint32))
 
 
-def _custom_call_signature(text):
-    """((dtype, rank) of the result, of each operand) of the one
+def _custom_call_signatures(text):
+    """((dtype, rank) of the result, of each operand) of each
     ``tpu_custom_call`` in a compiled module, in the form of
     ``bench.kernels.SIGNATURES``."""
     from bench import kernels
-    (line,) = [ln for ln in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in ln]
     arrays = lambda s: tuple((t, len(d.split(","))) for t, d in
                              kernels._ARRAY.findall(s))
-    result = line.split("=", 1)[1].split("custom-call(")[0]
-    operands = re.search(r"operand_layout_constraints=\{(.*?\})\}",
-                         line).group(1)
-    return arrays(result), arrays(operands)
+    out = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        result = line.split("=", 1)[1].split("custom-call(")[0]
+        operands = re.search(r"operand_layout_constraints=\{(.*?\})\}",
+                             line).group(1)
+        out.append((arrays(result), arrays(operands)))
+    return out
+
+
+def _custom_call_signature(text):
+    """The signature of the one ``tpu_custom_call`` in a compiled
+    module."""
+    (sig,) = _custom_call_signatures(text)
+    return sig
 
 
 def _arena_relayouts(text, n_pages):
@@ -171,3 +181,56 @@ def test_prefill_kv_write_compiles(spec):
 def test_int8_matmul_compiles(spec):
     _compile(int8_matmul, spec((256, D_MODEL), jnp.float32),
              spec((D_MODEL, D_FF), jnp.int8), spec((D_FF,), jnp.float32))
+
+
+# Mellum2-12B-A2.5B's expert layer on one chip of 8 (8 experts of
+# d 2304 x 896 held): a decode step's 16 rows (row tile 8) and a train
+# step's 8 rows of 513 (row tile 256), each product and its gradients
+@pytest.mark.parametrize("M,tm", [(184, 8), (35072, 256)])
+@pytest.mark.parametrize("K,N", [(2304, 896), (896, 2304)])
+def test_grouped_matmul_compiles(spec, M, tm, K, N):
+    """The kernels compile at the cell's shapes and keep the signatures
+    the benchmark tells them by."""
+    from bench import expert_matmul
+    from repro.kernels.grouped_matmul import gmm, tgmm
+    tg, nu = spec((M // tm,), jnp.int32), spec((1,), jnp.int32)
+    w = spec((8, K, N), jnp.float32)
+    cases = (
+        (functools.partial(gmm, tm=tm), (spec((M, K), jnp.float32), w, tg, nu),
+         "gmm"),
+        (functools.partial(gmm, tm=tm, transpose_rhs=True),
+         (spec((M, N), jnp.float32), w, tg, nu), "gmm"),
+        (functools.partial(tgmm, tm=tm),
+         (spec((M, K), jnp.float32), spec((M, N), jnp.float32),
+          spec((8,), jnp.int32), tg, nu), "tgmm"))
+    for fn, args, kind in cases:
+        text = _compile(fn, *args).as_text()
+        assert _custom_call_signature(text) in \
+            expert_matmul.SIGNATURES[kind]
+
+
+def test_mellum_decode_chunk_compiles(spec, monkeypatch):
+    """The engine's decode chunk for the Mellum2 cell (16 slots of 12 +
+    512 positions, pages of 16, 4 layers, 8 of 64 experts held) on the
+    kernel routes: three grouped products per layer beside the paged
+    attention kernel."""
+    from repro import configs
+    from repro.models import init_params
+    from repro.rl.rollout import rollout_rows_chunk, start_row_pool
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "compile")
+    cfg = configs.get_config("mellum2-12b-a2.5b").replace(
+        n_layers=4, vocab=12288, experts_held=8)
+    as_spec = lambda tree: jax.tree.map(lambda a: spec(a.shape, a.dtype),
+                                        tree)
+    params = as_spec(jax.eval_shape(
+        lambda k: init_params(cfg, k, jnp.float32), jax.random.PRNGKey(0)))
+    state = as_spec(jax.eval_shape(lambda: start_row_pool(
+        cfg, 16, 12 + 512, 12, kv_layout="paged", kv_page_size=16)))
+    text = rollout_rows_chunk.lower(params, cfg, state,
+                                    spec((2,), jnp.uint32), n_steps=16
+                                    ).compile().as_text()
+    from bench import expert_matmul
+    gmm = set(expert_matmul.SIGNATURES["gmm"])
+    # gate, up and down in the body of each of the two layer segments
+    # (3 sliding, 1 full), and no weight gradient
+    assert sum(sig in gmm for sig in _custom_call_signatures(text)) == 3 * 2

@@ -206,6 +206,43 @@ def test_engine_counters_advance_once_per_dispatched_round():
     assert (rounds.value, live.value) == (r0 + 2, l0 + 8)
 
 
+def test_engine_charges_expert_tally_across_pool_restarts():
+    """An expert model's pool tallies its products on the device and the
+    harvest charges the differences to ``moe.*``: one product call per
+    expert layer for each admission prefill and each decode step.  A
+    pool started again after an abort charges from zero again."""
+    from repro import configs
+    reg = obs_metrics.registry()
+    cfg = configs.get_smoke("mellum2-12b-a2.5b")
+    ex = GeneratorExecutor(
+        cfg, ArithmeticTasks(prompt_len=8, max_operand=9, ops="+", seed=0),
+        n_prompts=2, n_per_prompt=2, max_new=4, chunk=2, seed=0)
+    ex.set_weights(_params(cfg), version=0)
+    ex.engine_configure(max_running_rows=4, kv_layout="paged",
+                        kv_page_size=4)
+    names = ("moe.pairs_held", "moe.experts_hit", "moe.products")
+    before = [reg.counter(n).value for n in names]
+    ex.engine_enqueue(0, bound=0)                # 4 rows fill the 4 slots
+    items = []
+    while not items:
+        items = ex.engine_round(["completions"])
+    pairs, hits, products = (reg.counter(n).value - b
+                             for n, b in zip(names, before))
+    # 4 prefills and 2 chunks of 2 decode steps over 2 expert layers;
+    # the prefills run 8 prompt tokens, 4 for a sibling whose first page
+    # the radix cache holds; each decode step runs the 4 slots
+    assert products == 2 * (4 + 2 * 2)
+    assert 0 < hits <= products * cfg.held_experts
+    assert pairs == 2 * (8 + 4 + 8 + 4 + 4 * 4) * cfg.moe.top_k
+    ex.engine_abort()
+    ex.engine_enqueue(1, bound=0)
+    ex.engine_round(["completions"])
+    ex.engine_round(["completions"])
+    assert reg.counter("moe.products").value - before[2] == \
+        2 * (4 + 2 * 2) * 2
+    assert reg.gauge("moe.experts_held").value == cfg.held_experts
+
+
 def test_engine_abort_mid_decode_releases_everything():
     """An engine-mode run ending mid-decode must leak nothing: no parked
     pool state in the PartialRolloutCache, every slot free, no

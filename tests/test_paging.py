@@ -219,7 +219,7 @@ def _windowed_cfg():
         n_layers=2, d_model=32, n_heads=2, n_kv_heads=2, head_dim=16,
         d_ff=64, vocab=64, window=4, window_pattern=2,
         moe=MoEConfig(n_experts=2, top_k=1, n_shared=1, d_expert=64,
-                      router="sigmoid", capacity_factor=4.0)).validate()
+                      router="sigmoid")).validate()
 
 
 def test_engine_cache_contract_paged_vs_dense():
