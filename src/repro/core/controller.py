@@ -772,7 +772,10 @@ class AsyncExecutorController(SyncExecutorController):
         spawn_thread("consumer",
                      lambda: self._consumer_loop(first, last, stop,
                                                  train_iv, publish_wait))
-        deadline = time.monotonic() + self.timeout
+        # the deadline bounds a stall, not the run: it restarts with each
+        # trained step, so a run longer than ``timeout`` that keeps
+        # training is not cut
+        tick, deadline = self._tick, time.monotonic() + self.timeout
         stragglers: List[threading.Thread] = []
         while True:
             with threads_lock:
@@ -784,7 +787,9 @@ class AsyncExecutorController(SyncExecutorController):
                 grown = len(threads) > len(snapshot)
             if not alive and not grown:
                 break
-            if time.monotonic() > deadline:
+            if self._tick != tick:
+                tick, deadline = self._tick, time.monotonic() + self.timeout
+            elif time.monotonic() > deadline:
                 stragglers = alive
                 break
         if stragglers:
@@ -794,8 +799,9 @@ class AsyncExecutorController(SyncExecutorController):
                 t.join(timeout=5.0)
             if not errors:
                 raise TimeoutError(
-                    f"controller deadline ({self.timeout}s) exceeded; "
-                    "executor threads did not finish")
+                    f"controller deadline ({self.timeout}s without a "
+                    "trained step) exceeded; executor threads did not "
+                    "finish")
         if errors:
             self.shutdown()
             raise errors[0]

@@ -63,18 +63,47 @@ def attn_segments(cfg: ArchConfig, n_layers: int, offset: int = 0):
     return bb._segment_windows(cfg, n_layers, offset)
 
 
+def _attn_stacks(cfg: ArchConfig):
+    """(params key, n_layers, first layer's index) of each attention layer
+    stack, in the order prefill and decode walk them."""
+    if cfg.family == "moe":
+        fkd = cfg.moe.first_k_dense
+        dense = [("dense_layers", fkd, 0)] if fkd else []
+        return dense + [("moe_layers", cfg.n_layers - fkd, fkd)]
+    return [("layers", cfg.n_layers, 0)]
+
+
 def segment_layout(cfg: ArchConfig):
     """Cache segment layout [(n_layers, window), ...] matching the order in
     which prefill/decode walk the (possibly multiple) layer stacks."""
-    if cfg.family == "moe":
-        out = []
-        fkd = cfg.moe.first_k_dense
-        if fkd:
-            out += [(j - i, w) for (i, j, w) in attn_segments(cfg, fkd, 0)]
-        out += [(j - i, w) for (i, j, w) in
-                attn_segments(cfg, cfg.n_layers - fkd, fkd)]
-        return out
-    return [(j - i, w) for (i, j, w) in attn_segments(cfg, cfg.n_layers)]
+    return [(j - i, w) for _, n, off in _attn_stacks(cfg)
+            for (i, j, w) in attn_segments(cfg, n, off)]
+
+
+def segment_params(params, cfg: ArchConfig):
+    """Each attention segment's layers as ``(stacked params, window,
+    rotary table)``, in ``segment_layout`` order; a segment that spans
+    its whole stack is the stack itself (``a[0:n]`` takes no slice).
+
+    Weights do not change within a decode chunk, so the chunk programs
+    take these once, before their step loop, and hand them to every
+    ``decode_step``: a slice taken inside the loop is copied out of the
+    stack again at every step.  None for the families whose decode walks
+    no attention segments."""
+    if cfg.family not in ("dense", "vlm", "moe"):
+        return None
+    return [(jax.tree.map(lambda a: a[i:j], params[key]), w,
+             bb.layer_yarn(cfg, off + i))
+            for key, n, off in _attn_stacks(cfg)
+            for (i, j, w) in attn_segments(cfg, n, off)]
+
+
+def segment_slices(cfg: ArchConfig) -> int:
+    """How many attention segments are a proper sub-range of their layer
+    stack: the weight slices ``segment_params`` takes (0 where each stack
+    is one segment)."""
+    return sum(j - i < n for _, n, off in _attn_stacks(cfg)
+               for (i, j, _) in attn_segments(cfg, n, off))
 
 
 def _moe_tally(cfg, cache, tally):
@@ -171,18 +200,10 @@ def _write_seg(seg, kvs):
 def _prefill_collect(params, cfg, x, mrope_pos=None):
     """Run decoder stacks collecting per-segment stacked KVs; returns
     (x, kvs, the layers' summed tally)."""
-    if cfg.family == "moe":
-        stacks = []
-        fkd = cfg.moe.first_k_dense
-        if fkd:
-            stacks.append((params["dense_layers"], fkd, 0))
-        stacks.append((params["moe_layers"], cfg.n_layers - fkd, fkd))
-    else:
-        stacks = [(params["layers"], cfg.n_layers, 0)]
     kv_segs = []
     tally = 0.0
-    for stacked, n, off in stacks:
-        x, a, kvs = bb._run_decoder_stack(stacked, x, cfg, n, offset=off,
+    for key, n, off in _attn_stacks(cfg):
+        x, a, kvs = bb._run_decoder_stack(params[key], x, cfg, n, offset=off,
                                           mrope_pos=mrope_pos,
                                           collect_kv=True)
         kv_segs.extend(kvs)
@@ -201,37 +222,23 @@ def _extend_collect(params, cfg, x, prefix_kvs, q_offset: int):
     result is bit-for-bit what ``_prefill_collect`` computes for the
     same positions of the full prompt.  Also returns the layers' summed
     tally."""
-    if cfg.family == "moe":
-        stacks = []
-        fkd = cfg.moe.first_k_dense
-        if fkd:
-            stacks.append((params["dense_layers"], fkd, 0))
-        stacks.append((params["moe_layers"], cfg.n_layers - fkd, fkd))
-    else:
-        stacks = [(params["layers"], cfg.n_layers, 0)]
     kv_segs = []
     tally = 0.0
-    si = 0
-    for stacked, n, off in stacks:
-        for (i, j, w) in attn_segments(cfg, n, off):
-            seg = jax.tree.map(lambda a: a[i:j], stacked)
-            pk, pv = prefix_kvs[si]
+    for (seg, w, yarn), (pk, pv) in zip(segment_params(params, cfg),
+                                        prefix_kvs):
+        def body(h, inputs, w=w, yarn=yarn):
+            lp, pk_l, pv_l = inputs
+            h = constrain_batch(h)
+            hh = norm(h, lp["ln1"], cfg.norm)
+            y, kv = attn.gqa_extend(lp["attn"], hh, pk_l, pv_l, cfg,
+                                    q_offset=q_offset, window=w, yarn=yarn)
+            h = h + y
+            h, a = bb._ffn_block(lp, h, cfg)
+            return h, a, kv
 
-            def body(h, inputs, w=w, yarn=bb.layer_yarn(cfg, off + i)):
-                lp, pk_l, pv_l = inputs
-                h = constrain_batch(h)
-                hh = norm(h, lp["ln1"], cfg.norm)
-                y, kv = attn.gqa_extend(lp["attn"], hh, pk_l, pv_l, cfg,
-                                        q_offset=q_offset, window=w,
-                                        yarn=yarn)
-                h = h + y
-                h, a = bb._ffn_block(lp, h, cfg)
-                return h, a, kv
-
-            x, t, kvs = bb.scan_tallied(body, x, (seg, pk, pv), cfg, seg)
-            kv_segs.append(kvs)
-            tally = tally + t
-            si += 1
+        x, t, kvs = bb.scan_tallied(body, x, (seg, pk, pv), cfg, seg)
+        kv_segs.append(kvs)
+        tally = tally + t
     return x, kv_segs, tally
 
 
@@ -406,8 +413,12 @@ def _decode_seg_paged(stacked_params, seg, x, page_table, pos, cfg, window,
     return x, {"k": ak, "v": av}, tally
 
 
-def decode_step(params, cfg: ArchConfig, cache: Cache, tokens):
-    """tokens: [B, 1].  Returns (logits [B, V], new cache)."""
+def decode_step(params, cfg: ArchConfig, cache: Cache, tokens,
+                segments=None):
+    """tokens: [B, 1].  Returns (logits [B, V], new cache).
+
+    ``segments``: ``segment_params(params, cfg)``, taken once by a caller
+    that runs many steps on the same weights; taken here when absent."""
     pos = cache["pos"]
     x = bb._embed(params, cfg, tokens)
     B = tokens.shape[0]
@@ -419,33 +430,20 @@ def decode_step(params, cfg: ArchConfig, cache: Cache, tokens):
         mrope_pos = jnp.stack([tp, tp, tp], axis=0)
 
     if cfg.family in ("dense", "vlm", "moe"):
-        if cfg.family == "moe":
-            stacks = []
-            fkd = cfg.moe.first_k_dense
-            if fkd:
-                stacks.append((params["dense_layers"], fkd, 0))
-            stacks.append((params["moe_layers"], cfg.n_layers - fkd, fkd))
-        else:
-            stacks = [(params["layers"], cfg.n_layers, 0)]
+        if segments is None:
+            segments = segment_params(params, cfg)
         paged = "page_table" in cache
         new_segs = []
         tally = 0.0
-        si = 0
-        for stacked, n, off in stacks:
-            for (i, j, w) in attn_segments(cfg, n, off):
-                lp = jax.tree.map(lambda a: a[i:j], stacked)
-                yarn = bb.layer_yarn(cfg, off + i)
-                if paged:
-                    x, new_seg, t = _decode_seg_paged(
-                        lp, cache["segments"][si], x, cache["page_table"],
-                        pos, cfg, w, yarn=yarn)
-                else:
-                    x, new_seg, t = _decode_seg(
-                        lp, cache["segments"][si], x, pos, cfg, w,
-                        mrope_pos=mrope_pos, yarn=yarn)
-                new_segs.append(new_seg)
-                tally = tally + t
-                si += 1
+        for (lp, w, yarn), seg in zip(segments, cache["segments"]):
+            if paged:
+                x, new_seg, t = _decode_seg_paged(
+                    lp, seg, x, cache["page_table"], pos, cfg, w, yarn=yarn)
+            else:
+                x, new_seg, t = _decode_seg(lp, seg, x, pos, cfg, w,
+                                            mrope_pos=mrope_pos, yarn=yarn)
+            new_segs.append(new_seg)
+            tally = tally + t
         new_cache = {"pos": pos + 1, "segments": new_segs}
         if paged:
             new_cache["page_table"] = cache["page_table"]

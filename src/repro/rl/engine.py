@@ -48,6 +48,7 @@ from repro.core.offpolicy import PartialRolloutCache
 from repro.kernels import dispatch
 from repro.models.paging import PagePool, RadixCache, paged_blocks, \
     plan_admission, release_plan
+from repro.models.serve import segment_slices
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 from repro.rl import data as rl_data
@@ -388,6 +389,8 @@ class RolloutEngine:
                                            temperature=ex.temperature)
             self._rounds.inc()
             self._live_row_rounds.inc(len(self.tickets))
+            obs_metrics.registry().gauge("engine.segment_slices").set(
+                segment_slices(ex.cfg))
             for t in self.tickets.values():
                 t.chunks_done += 1
             state, emitted = self._harvest(state)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from repro.kernels import dispatch
 from repro.models import prefill, decode_step
+from repro.models.serve import segment_params
 from repro.rl.data import EOS, PAD
 
 
@@ -92,6 +93,7 @@ def rollout_chunk(params, cfg, state: RolloutState, key, *,
     """Generate up to n_steps tokens; resumable (partial rollout)."""
     cursor = state.cache["pos"] - (cfg.frontend_tokens
                                    if cfg.family == "vlm" else 0)
+    segments = segment_params(params, cfg)     # once, not once a step
 
     def body(carry, k):
         cache, logits, done = carry
@@ -101,7 +103,8 @@ def rollout_chunk(params, cfg, state: RolloutState, key, *,
         # action positions: keep mu consistent with the action mask
         lp = jnp.where(tok == PAD, 0.0, lp)
         new_done = done | (tok == EOS)
-        new_logits, cache = decode_step(params, cfg, cache, tok[:, None])
+        new_logits, cache = decode_step(params, cfg, cache, tok[:, None],
+                                        segments)
         return (cache, new_logits, new_done), (tok, lp)
 
     keys = jax.random.split(key, n_steps)
@@ -335,6 +338,7 @@ def rollout_rows_chunk(params, cfg, state: RolloutState, key, *,
             * state.cache["segments"][0]["k"].shape[2]
     else:
         clamp = T
+    segments = segment_params(params, cfg)     # once, not once a step
 
     def body(carry, k):
         tokens, blp, cache, logits, done = carry
@@ -345,7 +349,8 @@ def rollout_rows_chunk(params, cfg, state: RolloutState, key, *,
         col = cache["pos"]                         # [B] per-row cursors
         tokens = tokens.at[rows, col].set(tok, mode="drop")
         blp = blp.at[rows, col].set(lp, mode="drop")
-        new_logits, cache = decode_step(params, cfg, cache, tok[:, None])
+        new_logits, cache = decode_step(params, cfg, cache, tok[:, None],
+                                        segments)
         cache = {**cache, "pos": jnp.minimum(cache["pos"], clamp)}
         return (tokens, blp, cache, new_logits, new_done), None
 

@@ -231,6 +231,24 @@ def test_generator_exception_propagates_and_joins():
     assert threading.active_count() <= before   # no leaked threads
 
 
+class _SlowGenerator(GeneratorExecutor):
+    def begin_batch(self, batch_index=None):
+        time.sleep(0.4)
+        return super().begin_batch(batch_index)
+
+
+def test_run_longer_than_timeout_keeps_training():
+    """``timeout`` bounds a stall, not the whole run: a run that trains a
+    step every half second goes on past its ``timeout``."""
+    ctl = build(seed=0, staleness=1, max_steps=2, gen_cls=_SlowGenerator)
+    ctl.run()                                   # compiles every program
+    ctl.timeout, ctl.max_steps = 2.0, 8
+    t0 = time.monotonic()
+    history = ctl.run()
+    assert time.monotonic() - t0 > ctl.timeout
+    assert len(history) == 10
+
+
 def test_consumer_exception_unblocks_pool_and_joins():
     """A trainer-side failure must close the comms so workers blocked in
     channel recv / queue push unwind with ``Closed`` and join -- the

@@ -101,6 +101,43 @@ def _custom_call_signature(text):
     return sig
 
 
+def _computations(text):
+    """({name: instruction lines}, the entry's name) of a compiled
+    module."""
+    comps, entry, name = {}, None, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            name = head.group(2)
+            comps[name] = []
+            if head.group(1):
+                entry = name
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            comps[name].append(line.strip())
+    return comps, entry
+
+
+def _copied_shapes(comps, name):
+    """Result dims of each slice, dynamic slice and copy in computation
+    ``name``, and of each fusion it calls that holds one (with theirs);
+    not of the loops it runs."""
+    out = []
+    for line in comps[name]:
+        result = re.search(r" = \w+\[([\d,]*)\]", line)
+        dims = result and tuple(int(d) for d in result.group(1).split(",")
+                                if d)
+        fused = re.search(r"\sfusion\(.*calls=%([\w.\-]+)", line)
+        inner = fused and _copied_shapes(comps, fused.group(1))
+        if inner:
+            out += inner
+        if result and (inner or re.search(r"\s(slice|dynamic-slice|copy)\(",
+                                          line)):
+            out.append(dims)
+    return out
+
+
 def _arena_relayouts(text, n_pages):
     """Reshapes and copies of an arena-sized array in a compiled module."""
     return [ln.strip() for ln in text.splitlines()
@@ -147,11 +184,15 @@ def test_paged_decode_chunk_compiles(spec, monkeypatch):
     n_pages = 16 * 33
     assert state.cache["segments"][0]["k"].shape == \
         (1, n_pages + 1, 16, N_KV * HEAD_DIM)
-    text = rollout_rows_chunk.lower(params, cfg, state,
-                                    spec((2,), jnp.uint32), n_steps=16
-                                    ).compile().as_text()
+    compiled = rollout_rows_chunk.lower(params, cfg, state,
+                                        spec((2,), jnp.uint32), n_steps=16
+                                        ).compile()
+    text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert _arena_relayouts(text, n_pages) == []
+    # its one attention segment is its whole layer stack: taking the
+    # segment once per chunk changes nothing in this program
+    assert compiled.memory_analysis().temp_size_in_bytes == 378_713_088
 
 
 def test_flash_attention_compiles(spec):
@@ -213,7 +254,9 @@ def test_mellum_decode_chunk_compiles(spec, monkeypatch):
     """The engine's decode chunk for the Mellum2 cell (16 slots of 12 +
     512 positions, pages of 16, 4 layers, 8 of 64 experts held) on the
     kernel routes: three grouped products per layer beside the paged
-    attention kernel."""
+    attention kernel.  The weights of its two attention segments (3
+    sliding layers, 1 full) are sliced out of the layer stack once, in
+    the entry computation, and not in each step of the chunk's loop."""
     from repro import configs
     from repro.models import init_params
     from repro.rl.rollout import rollout_rows_chunk, start_row_pool
@@ -234,3 +277,16 @@ def test_mellum_decode_chunk_compiles(spec, monkeypatch):
     # gate, up and down in the body of each of the two layer segments
     # (3 sliding, 1 full), and no weight gradient
     assert sum(sig in gmm for sig in _custom_call_signatures(text)) == 3 * 2
+    comps, entry = _computations(text)
+    (step_loop,) = [re.search(r"body=%([\w.\-]+)", line).group(1)
+                    for line in comps[entry] if " while(" in line]
+    stack = [a.shape for a in jax.tree.leaves(params["moe_layers"])]
+    segments = {(n,) + s[1:] for s in stack for n in (3, 1)}
+    layer_experts = {s[1:] for s in stack if len(s) == 4}
+    in_loop = set(_copied_shapes(comps, step_loop))
+    assert not in_loop & segments
+    assert not in_loop & layer_experts
+    expert_segments = [(n,) + s[1:] for s in stack if len(s) == 4
+                       for n in (3, 1)]
+    assert sorted(d for d in _copied_shapes(comps, entry)
+                  if d in expert_segments) == sorted(expert_segments)

@@ -206,6 +206,31 @@ def test_engine_counters_advance_once_per_dispatched_round():
     assert (rounds.value, live.value) == (r0 + 2, l0 + 8)
 
 
+@pytest.mark.parametrize("arch,n_slices", [("micro", 0),
+                                           ("mellum2-12b-a2.5b", 2)])
+def test_engine_sets_segment_slices_gauge(arch, n_slices):
+    """A round that dispatches a decode chunk sets ``engine.segment_slices``
+    to the weight slices the chunk takes once, before its step loop: none
+    for a model whose layer stack is one segment, one per segment of
+    Mellum2's (a sliding layer, then a full one)."""
+    from repro import configs
+    gauge = obs_metrics.registry().gauge("engine.segment_slices")
+    gauge.set(-1)
+    cfg = micro_cfg() if arch == "micro" else configs.get_smoke(arch)
+    ex = GeneratorExecutor(
+        cfg, ArithmeticTasks(prompt_len=8, max_operand=9, ops="+", seed=0),
+        n_prompts=2, n_per_prompt=2, max_new=4, chunk=2, seed=0)
+    ex.set_weights(_params(cfg), version=0)
+    ex.engine_configure(max_running_rows=4, kv_layout="paged",
+                        kv_page_size=4)
+    ex.engine_round(["completions"])             # nothing to decode
+    assert gauge.value == -1
+    ex.engine_enqueue(0, bound=0)
+    ex.engine_round(["completions"])
+    assert gauge.value == n_slices
+    ex.engine_abort()
+
+
 def test_engine_charges_expert_tally_across_pool_restarts():
     """An expert model's pool tallies its products on the device and the
     harvest charges the differences to ``moe.*``: one product call per
